@@ -54,8 +54,8 @@ def bench_reference_vs_kernel(quick: bool = False):
     """Scan-based jnp reference vs the Pallas crossbar_mac kernel.
 
     On CPU the kernel runs in interpret mode (every grid step is traced
-    Python), so the reference wins; on a real TPU ``interpret=False`` flips
-    the comparison.  Both numbers land in the JSON either way so the ratio
+    Python), so the reference wins; on a real TPU the compiled kernel
+    flips the comparison.  Both numbers land in the JSON either way so the ratio
     is tracked per-commit.
     """
     b, k, n = (8, 64, 64) if quick else (16, 128, 128)

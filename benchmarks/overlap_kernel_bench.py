@@ -101,11 +101,13 @@ def _time_overlap_decode(use_kernel: bool, warmup_steps: int,
          for r in lane.slots], jnp.int32))
     m = jnp.asarray([1 if r is not None else 0 for r in lane.slots],
                     jnp.int32)
-    tok, cache = lane.decode(lane.params, toks, cache, m, leak)
+    tiles = ex.resident_tiles("A")
+    tok, cache = lane.decode(lane.params, tiles, toks, cache, m, leak)
     jax.block_until_ready(tok)
     t0 = time.perf_counter()
     for _ in range(timed_calls):
-        tok, cache = lane.decode(lane.params, toks, cache, m, leak)
+        tok, cache = lane.decode(lane.params, tiles, toks, cache, m,
+                                 leak)
     jax.block_until_ready(tok)
     per_decode = (time.perf_counter() - t0) / timed_calls
     lane.cache = cache

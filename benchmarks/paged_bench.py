@@ -50,10 +50,11 @@ reads.  Hard gates are the STRUCTURAL properties: streamed VMEM
 scratch bytes CONSTANT across all windows (the O(page_block) claim —
 the ring + online-softmax stats; ``streamed_resident_bytes`` records
 the current whole-pool lowering's residency alongside, informational),
-bounded-ulp parity (fp32 maxdiff < 1e-5) with stable argmax, and ZERO
-``paged_fallback`` dispatches — the no-silent-fallback counter wired
-straight into the exit code.  The streamed/scratch throughput ratio at
-the longest window is soft-gated at >= _LONGCTX_RATIO_GATE (0.9):
+bounded-ulp parity (fp32 maxdiff < 1e-5) with stable argmax, and every
+streamed call counted as a ``paged_streamed`` dispatch (a failing lane
+raises; there is no fallback to count).  The streamed/scratch
+throughput ratio at the longest window is soft-gated at
+>= _LONGCTX_RATIO_GATE (0.9):
 both lanes are interpret-mode wall clocks on a shared CPU runner, so a
 hard 1.0 gate flaked on scheduler jitter unrelated to correctness —
 the full ratio is still recorded per window in BENCH_paged.json.
@@ -94,7 +95,7 @@ _PLENS = (8, 13, 22, 35, 50, 62)
 _THROUGHPUT_GATE = 0.7
 # soft margin on the longctx streamed/scratch wall-clock ratio: timing
 # noise between two CPU-interpret lanes must not flip CI (the
-# structural gates — VMEM constancy, parity, argmax, zero fallbacks —
+# structural gates — VMEM constancy, parity, argmax, dispatch counts —
 # stay hard)
 _LONGCTX_RATIO_GATE = 0.9
 
@@ -458,7 +459,6 @@ def _longctx_phase(windows=(16, 32, 64), repeats=3):
         "parity_maxdiff": max(r["parity_maxdiff"] for r in rows),
         "argmax_stable": argmax_stable,
         "dispatch_calls": calls,
-        "fallback_delta": calls["paged_fallback"],
     }
 
 
@@ -542,14 +542,14 @@ def accepted(res) -> bool:
                     for a in pre["arms"].values())
             # long-context two-lane sweep: structural gates hard
             # (constant VMEM scratch, bounded-ulp parity, stable argmax,
-            # zero silent fallbacks); the wall-clock ratio gets a noise
+            # streamed dispatches counted); the wall-clock ratio gets a noise
             # margin — two interpret-mode lanes on a shared CPU runner
             # jitter for reasons unrelated to correctness
             and lc["ratio_at_longest"] >= _LONGCTX_RATIO_GATE
             and lc["streamed_vmem_constant"]
             and lc["parity_maxdiff"] < 1e-5
             and lc["argmax_stable"]
-            and lc["fallback_delta"] == 0)
+            and lc["dispatch_calls"]["paged_streamed"] > 0)
 
 
 def main(argv=None):
@@ -602,8 +602,8 @@ def main(argv=None):
           f"whole-pool lowering) vs scratch "
           f"x{lc['scratch_vmem_growth']:.0f} growth, parity maxdiff "
           f"{lc['parity_maxdiff']:.2e} (gate < 1e-5), argmax stable "
-          f"({lc['argmax_stable']}), fallbacks {lc['fallback_delta']} "
-          f"(want 0)")
+          f"({lc['argmax_stable']}), streamed dispatches "
+          f"{lc['dispatch_calls']['paged_streamed']} (want > 0)")
     return 0 if ok else 1
 
 

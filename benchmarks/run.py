@@ -30,6 +30,7 @@ from benchmarks import paged_bench as pgb  # noqa: E402
 from benchmarks import paper_benches as pb  # noqa: E402
 from benchmarks.meta import append_trajectory, write_stamped  # noqa: E402
 from repro import obs  # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 
 
 BENCHES = [
@@ -64,6 +65,7 @@ def main(argv=None) -> None:
     ap.add_argument("--json", default="BENCH_crossstack.json",
                     help="write all results to this JSON artifact")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     results = {}
     # --quick is CI's "Benchmark smoke" step, which is followed by

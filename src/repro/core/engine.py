@@ -24,6 +24,7 @@ Two execution paths share one ``ProgrammedLinear`` representation:
 from __future__ import annotations
 
 import dataclasses
+import functools
 from collections.abc import Mapping
 from typing import Optional
 
@@ -46,7 +47,6 @@ class EngineConfig:
     mode: str = "expansion"            # "expansion" | "deepnet"
     params: CrossStackParams = PAPER
     use_kernel: bool = False           # route MAC through the Pallas kernel
-    interpret: bool = True             # Pallas interpret mode (CPU container)
     swap_leakage: bool = False         # perturb reads with write-plane
     # leakage while a hot-swap is in flight (fidelity studies; breaks
     # bit-exactness of mid-swap reads by at most the ADC residual)
@@ -101,16 +101,31 @@ def program(w: jax.Array, cfg: EngineConfig) -> ProgrammedLinear:
     w_int, w_scale = quant.quantize_weights(w, q)
     r = cfg.tile_rows
     t = -(-k // r)
-    w_int = _pad_to(w_int, t * r, axis=0)
     n_pad = -(-n // cfg.tile_cols) * cfg.tile_cols
-    w_int = _pad_to(w_int, n_pad, axis=1)
     if q.per_channel:
         w_scale = _pad_to(w_scale, n_pad, axis=1)
+    pos, neg = _cell_planes(w_int, q, t, r, n_pad)
+    return ProgrammedLinear(pos, neg, w_scale, k, n)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3, 4))
+def _cell_planes(w_int: jax.Array, q: QuantConfig, t: int, r: int,
+                 n_pad: int):
+    """Signed integer weights (K, N) -> int8 (S, T, R, N_pad) pos/neg
+    cell-code planes, zero-padded.
+
+    One fused program: the digit extraction is exact integer arithmetic,
+    so jitting it leaves every code as the eager ops would produce it,
+    while XLA writes the int8 planes directly — the (S, K, N) int32
+    digit stacks never materialize, and programming an LM head peaks
+    near its int8 output.  (Quantization stays outside: under jit XLA
+    may rewrite ``amax / qmax`` into a reciprocal multiply, which moves
+    scales by an ulp and can flip a code at a rounding boundary.)"""
+    w_int = _pad_to(_pad_to(w_int, t * r, axis=0), n_pad, axis=1)
     pos, neg = quant.to_slices(w_int, q)               # (S, T*R, N_pad)
     s = q.n_slices
-    pos = pos.reshape(s, t, r, n_pad).astype(jnp.int8)
-    neg = neg.reshape(s, t, r, n_pad).astype(jnp.int8)
-    return ProgrammedLinear(pos, neg, w_scale, k, n)
+    return (pos.reshape(s, t, r, n_pad).astype(jnp.int8),
+            neg.reshape(s, t, r, n_pad).astype(jnp.int8))
 
 
 # ---------------------------------------------------------------------------

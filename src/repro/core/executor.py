@@ -137,6 +137,10 @@ class CrossbarExecutor:
         # ARGUMENT of the compiled step (0.0 outside a swap window, the
         # live value inside it) instead of a trace-time constant
         self._leak_override: Optional[Any] = None
+        # ambient tile override: a serving closure traces under
+        # tile_scope(<its tiles argument>) so the resident planes are
+        # ARGUMENTS of the compiled step, not constants baked into it
+        self._tile_override: Optional[Dict[str, Any]] = None
         # cached device scalars for current_leak_codes(): cfg is frozen,
         # so both values are constants — one host->device put each, not
         # one per decode step
@@ -195,8 +199,8 @@ class CrossbarExecutor:
     def read_tenant(self, tenant: str):
         """Ambient-tenant scope: reads (and eager programming checks)
         inside the block address ``tenant``'s plane set.  Wrap a serving
-        closure's trace in this so the jitted step reads that tenant's
-        tiles as its trace constants."""
+        closure's trace in this (with :meth:`tile_scope`) so the jitted
+        step reads that tenant's tiles from its own arguments."""
         self._check_tenant(tenant)
         prev, self._read_tenant = self._read_tenant, tenant
         try:
@@ -226,6 +230,31 @@ class CrossbarExecutor:
                       "version": self.version(t),
                       "modes": {"expansion": n_exp, "deepnet": n_deep}}
         return out
+
+    def resident_tiles(self, tenant: Optional[str] = None
+                       ) -> Dict[str, Any]:
+        """The tenant's read-active plane per resident weight, by name:
+        the pytree a compiled serving step takes as an argument (see
+        :meth:`tile_scope`).  Tenant defaults to the ambient scope."""
+        tenant = self._resolve_tenant(tenant)
+        return {name: bank.active_for(tenant)
+                for name, bank in self._cache.items()
+                if bank.has_tenant(tenant)}
+
+    @contextlib.contextmanager
+    def tile_scope(self, tiles: Dict[str, Any]):
+        """Ambient tile override: reads inside the block take each
+        weight's planes from ``tiles`` (a :meth:`resident_tiles` dict)
+        instead of the bank.  Trace a serving closure under this with
+        the closure's own tiles *argument*: the compiled step then holds
+        no tile constants (GBs at published widths) and no second copy
+        of the planes in device memory.  Mode and layout still come from
+        the bank — they are static Python, fixed at program time."""
+        prev, self._tile_override = self._tile_override, tiles
+        try:
+            yield self
+        finally:
+            self._tile_override = prev
 
     # -- write-plane leakage (deep-net overlap reads) ------------------------
 
@@ -681,7 +710,8 @@ class CrossbarExecutor:
                 f"tenant {tenant!r} planes are mid-write (in-place swap "
                 f"in flight); reads resume after promote()")
         bank = self._cache[name]
-        pw = bank.active_for(tenant)
+        pw = (bank.active_for(tenant) if self._tile_override is None
+              else self._tile_override[name])
         mode = bank.mode_for(tenant)
         cfg = self._read_cfg(mode)
         n_in = self._n_in[name]

@@ -49,8 +49,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import smem_scalar_spec, tpu_compiler_params
+from repro import kernels
 
 
 def _adc(acc, adc_bits: int, full_scale: float):
@@ -74,7 +75,7 @@ def _kernel(leak_ref, x_ref, pos_ref, neg_ref, out_ref, *, in_bits: int,
     full_scale = float(full_scale_rows * (base - 1))
     leak = leak_ref[0, 0]                                 # common-mode code
     x = x_ref[...].astype(jnp.int32)                      # (B, R)
-    u = (x + (1 << in_bits)) % (1 << in_bits)             # two's complement
+    u = x & ((1 << in_bits) - 1)                          # two's complement
 
     acc = jnp.zeros_like(out_ref)
     for p in range(in_bits):
@@ -94,12 +95,11 @@ def _kernel(leak_ref, x_ref, pos_ref, neg_ref, out_ref, *, in_bits: int,
 
 @functools.partial(jax.jit, static_argnames=(
     "in_bits", "adc_bits", "bits_per_cell", "rows_per_adc",
-    "full_scale_rows", "block_b", "block_n", "interpret"))
+    "full_scale_rows", "block_b", "block_n"))
 def crossbar_mac(x_int, pos, neg, leak_codes=0.0, *, in_bits: int,
                  adc_bits: int, bits_per_cell: int, rows_per_adc: int,
                  full_scale_rows: Optional[int] = None,
-                 block_b: int = 128, block_n: int = 128,
-                 interpret: bool = True):
+                 block_b: int = 128, block_n: int = 128):
     """x_int (B, K) int32, pos/neg (S, K, N) int8 -> (B, N) f32 code units.
 
     ``leak_codes`` is the write-plane common-mode leakage in pre-ADC code
@@ -109,7 +109,6 @@ def crossbar_mac(x_int, pos, neg, leak_codes=0.0, *, in_bits: int,
     ``rows_per_adc``; ops.py passes the mode's group when an odd row-tile
     count forces smaller conversions).  K must be a multiple of
     rows_per_adc; B of block_b; N of block_n (ops.py pads).
-    interpret=True on CPU; False on real TPU.
     """
     b, k = x_int.shape
     s, k2, n = pos.shape
@@ -126,7 +125,8 @@ def crossbar_mac(x_int, pos, neg, leak_codes=0.0, *, in_bits: int,
                           full_scale_rows=full_scale_rows),
         grid=grid,
         in_specs=[
-            smem_scalar_spec(lambda i, j, t: (0, 0)),
+            pl.BlockSpec((1, 1), lambda i, j, t: (0, 0),
+                         memory_space=pltpu.SMEM),
             pl.BlockSpec((block_b, rows_per_adc), lambda i, j, t: (i, t)),
             pl.BlockSpec((s, rows_per_adc, block_n),
                          lambda i, j, t: (0, t, j)),
@@ -135,7 +135,7 @@ def crossbar_mac(x_int, pos, neg, leak_codes=0.0, *, in_bits: int,
         ],
         out_specs=pl.BlockSpec((block_b, block_n), lambda i, j, t: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(leak, x_int, pos, neg)

@@ -88,8 +88,7 @@ def crossbar_matmul(x, pw, cfg, leak_codes=0.0):
         in_bits=q.in_bits, adc_bits=q.adc_bits,
         bits_per_cell=q.bits_per_cell, rows_per_adc=rows_per_adc,
         full_scale_rows=full_scale_rows,
-        block_b=block_b, block_n=min(block_n, pos.shape[-1]),
-        interpret=cfg.interpret)
+        block_b=block_b, block_n=min(block_n, pos.shape[-1]))
 
     y = y[: xb.shape[0], :n_pad]
     y = y * x_scale * pw.w_scale[..., :n_pad]

@@ -25,12 +25,12 @@ S * in_bits MXU matmuls per tile.
 from __future__ import annotations
 
 import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
+from repro import kernels
 
 
 def _adc(acc, adc_bits: int, full_scale: float):
@@ -98,11 +98,11 @@ def _kernel(x_ref, w_ref, scale_ref, out_ref, *, w_bits: int, in_bits: int,
 
 @functools.partial(jax.jit, static_argnames=(
     "w_bits", "in_bits", "adc_bits", "bits_per_cell", "rows_per_adc",
-    "block_b", "block_n", "block_k", "interpret"))
+    "block_b", "block_n", "block_k"))
 def deepnet_stream(x_int, w, w_scale, *, w_bits: int, in_bits: int,
                    adc_bits: int, bits_per_cell: int, rows_per_adc: int,
                    block_b: int = 128, block_n: int = 128,
-                   block_k: int = 0, interpret: bool = True):
+                   block_k: int = 0):
     """x_int (B, K) int32, w (K, N) float, w_scale (1, N) -> (B, N) f32.
 
     ``block_k`` (0 = ``rows_per_adc``) is the streamed weight-tile depth:
@@ -132,7 +132,7 @@ def deepnet_stream(x_int, w, w_scale, *, w_bits: int, in_bits: int,
         ],
         out_specs=pl.BlockSpec((block_b, block_n), lambda i, j, t: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(x_int, w, w_scale)

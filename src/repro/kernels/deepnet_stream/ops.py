@@ -56,7 +56,7 @@ def stream_linear(x, w, cfg):
         x_pad, w_p, s_pad, w_bits=q.w_bits, in_bits=q.in_bits,
         adc_bits=q.adc_bits, bits_per_cell=q.bits_per_cell,
         rows_per_adc=rows, block_b=block_b, block_n=block_n,
-        block_k=block_k, interpret=cfg.interpret)
+        block_k=block_k)
 
     y = y[: xb.shape[0], : n] * x_scale * w_scale[..., :n]
     return y.reshape(*lead, n)

@@ -13,12 +13,12 @@ all live in VMEM; the sweep loop is unrolled at trace time.
 from __future__ import annotations
 
 import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import tpu_compiler_params
+from repro import kernels
 
 
 def _kernel(g_ref, vin_ref, vrow_ref, vcol_ref, orow_ref, ocol_ref, *,
@@ -51,10 +51,9 @@ def _kernel(g_ref, vin_ref, vrow_ref, vcol_ref, orow_ref, ocol_ref, *,
     ocol_ref[...] = v_col
 
 
-@functools.partial(jax.jit, static_argnames=("g_w", "omega", "sweeps",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("g_w", "omega", "sweeps"))
 def jacobi_sweeps(g, v_in, v_row, v_col, *, g_w: float, omega: float = 1.0,
-                  sweeps: int = 8, interpret: bool = True):
+                  sweeps: int = 8):
     """Run ``sweeps`` fused Jacobi iterations.  g/(v_row/v_col): (n, m);
     v_in: (n, 1) column vector of source voltages."""
     n, m = g.shape
@@ -68,6 +67,6 @@ def jacobi_sweeps(g, v_in, v_row, v_col, *, g_w: float, omega: float = 1.0,
                   pl.BlockSpec((n, m), lambda: (0, 0))],
         out_specs=(pl.BlockSpec((n, m), lambda: (0, 0)),
                    pl.BlockSpec((n, m), lambda: (0, 0))),
-        compiler_params=tpu_compiler_params(),
-        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(),
+        interpret=kernels.interpret_mode(),
     )(g, v_in, v_row, v_col)

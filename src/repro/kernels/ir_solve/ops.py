@@ -9,8 +9,7 @@ from repro.kernels.ir_solve.kernel import jacobi_sweeps
 
 def solve(g_dev, v_in, r_wire: float = PAPER.r_wire,
           r_access: float | None = None, n_iter: int = 2000,
-          sweeps_per_call: int = 16, omega: float = 1.0,
-          interpret: bool = True):
+          sweeps_per_call: int = 16, omega: float = 1.0):
     """Drop-in for core/ir_drop.jacobi_planar built on the Pallas kernel.
 
     Returns (i_out, v_row, v_col)."""
@@ -26,7 +25,6 @@ def solve(g_dev, v_in, r_wire: float = PAPER.r_wire,
     for _ in range(max(1, n_iter // sweeps_per_call)):
         v_row, v_col = jacobi_sweeps(g, vin_col, v_row, v_col,
                                      g_w=float(g_w), omega=omega,
-                                     sweeps=sweeps_per_call,
-                                     interpret=interpret)
+                                     sweeps=sweeps_per_call)
     i_out = g_w * v_col[n - 1, :]
     return i_out, v_row, v_col

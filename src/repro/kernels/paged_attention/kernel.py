@@ -34,8 +34,7 @@ caps this lane at short windows.
 — a block-streamed online-softmax (flash-style) kernel.  Grid =
 (n_page_blocks,) with the whole batch folded into each block step; the
 page table, fill lengths and query offsets arrive as *scalar-prefetch*
-operands (``compat.prefetch_grid_spec`` →
-``pltpu.PrefetchScalarGridSpec``; on TPU the table is in SMEM before
+operands (``pltpu.PrefetchScalarGridSpec``; on TPU the table is in SMEM before
 the first DMA issues).  Each step gathers ONE page block of K/V into a
 two-slot VMEM scratch ring — block j+1 prefetches into the other slot
 while block j is attended — and folds it into running max /
@@ -78,8 +77,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import (prefetch_grid_spec, smem_scalar_spec,
-                                  tpu_compiler_params)
+from repro import kernels
+
+
+def _smem_scalar(index_map):
+    """BlockSpec of one (1, 1) per-row scalar, held in SMEM."""
+    return pl.BlockSpec((1, 1), index_map, memory_space=pltpu.SMEM)
 
 
 def _kernel(pt_ref, len_ref, off_ref, q_ref, k_ref, v_ref, out_ref,
@@ -120,16 +123,14 @@ def _kernel(pt_ref, len_ref, off_ref, q_ref, k_ref, v_ref, out_ref,
     out_ref[0] = out.reshape(sq, hq, hd)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal",))
 def paged_attention_kernel(q, k_pages, v_pages, page_table, kv_len,
-                           q_offset, *, causal: bool = True,
-                           interpret: bool = True):
+                           q_offset, *, causal: bool = True):
     """q (B, sq, hq, hd); k/v pages (P+1, ps, kv, hd); page_table
     (B, P_seq) int32; kv_len/q_offset (B,) int32 -> (B, sq, hq, hd).
 
     ``kv_len`` and ``q_offset`` are traced operands: rows at different
-    cache depths share ONE lowered kernel.  interpret=True on CPU; False
-    on real TPU.
+    cache depths share ONE lowered kernel.
     """
     b, sq, hq, hd = q.shape
     p1, ps, kv, _ = k_pages.shape
@@ -142,8 +143,8 @@ def paged_attention_kernel(q, k_pages, v_pages, page_table, kv_len,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, p_seq), lambda i: (i, 0)),
-            smem_scalar_spec(lambda i: (i, 0)),
-            smem_scalar_spec(lambda i: (i, 0)),
+            _smem_scalar(lambda i: (i, 0)),
+            _smem_scalar(lambda i: (i, 0)),
             pl.BlockSpec((1, sq, hq, hd), lambda i: (i, 0, 0, 0)),
             pl.BlockSpec((p1, ps, kv, hd), lambda i: (0, 0, 0, 0)),
             pl.BlockSpec((p1, ps, kv, hd), lambda i: (0, 0, 0, 0)),
@@ -154,9 +155,9 @@ def paged_attention_kernel(q, k_pages, v_pages, page_table, kv_len,
             pltpu.VMEM((depth, kv, hd), k_pages.dtype),
             pltpu.VMEM((depth, kv, hd), v_pages.dtype),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
-        interpret=interpret,
+        interpret=kernels.interpret_mode(),
     )(page_table,
       kv_len.astype(jnp.int32).reshape(b, 1),
       q_offset.astype(jnp.int32).reshape(b, 1),
@@ -253,18 +254,13 @@ def resolve_block_pages(pages_per_seq: int, block_pages: int) -> int:
     return bp
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "causal", "interpret", "block_pages", "force_compat_fallback"))
+@functools.partial(jax.jit, static_argnames=("causal", "block_pages"))
 def paged_attention_streamed(q, k_pages, v_pages, page_table, kv_len,
                              q_offset, *, causal: bool = True,
-                             interpret: bool = True, block_pages: int = 16,
-                             force_compat_fallback: bool = False):
+                             block_pages: int = 16):
     """Streamed-lane entry point; same signature/contract surface as
     ``paged_attention_kernel`` plus ``block_pages`` (pages per streamed
     block; clamped to a divisor of the table width).
-
-    ``force_compat_fallback`` routes through the plain-GridSpec shim
-    path even when ``PrefetchScalarGridSpec`` exists (compat test hook).
     """
     b, sq, hq, hd = q.shape
     p1, ps, kv, _ = k_pages.shape
@@ -272,7 +268,7 @@ def paged_attention_streamed(q, k_pages, v_pages, page_table, kv_len,
     bp = resolve_block_pages(p_seq, block_pages)
     n_blocks = p_seq // bp
     g = hq // kv
-    grid_kwargs = prefetch_grid_spec(
+    grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(n_blocks,),
         in_specs=[
@@ -293,15 +289,13 @@ def paged_attention_streamed(q, k_pages, v_pages, page_table, kv_len,
             pltpu.VMEM((b, kv, g, sq), jnp.float32),
             pltpu.VMEM((b, kv, g, sq, hd), jnp.float32),
         ],
-        scalar_shapes=[(b, p_seq), (b, 1), (b, 1)],
-        force_fallback=force_compat_fallback,
     )
     return pl.pallas_call(
         functools.partial(_stream_body, page_size=ps, block_pages=bp,
                           n_blocks=n_blocks, causal=causal),
         out_shape=jax.ShapeDtypeStruct((b, sq, hq, hd), q.dtype),
-        interpret=interpret,
-        **grid_kwargs,
+        grid_spec=grid_spec,
+        interpret=kernels.interpret_mode(),
     )(page_table.astype(jnp.int32),
       kv_len.astype(jnp.int32).reshape(b, 1),
       q_offset.astype(jnp.int32).reshape(b, 1),

@@ -17,28 +17,12 @@ Two lanes, dispatched by window size (``lane="auto"``):
   table is at least ``stream_min_pages`` pages wide (0 disables it).
 
 Every dispatch lands in the global telemetry registry as
-``crossstack_dispatch_total{path=paged_scratch|paged_streamed|
-paged_fallback, geometry}`` — bumped per call, i.e. per trace under jit,
-the same accounting ``core/engine.matmul`` uses — so CI can pin which
-lane served each decode closure (``paged_path_calls`` is the summed
-view).  **No silent reference fallback**: if the streamed lane was
-selected but its kernel fails, the dispatcher warns ONCE per geometry,
-counts ``path="paged_fallback"``, and routes to the *scratch kernel* —
-never the jnp reference scan — mirroring crossbar_mac's
-no-silent-fallback contract.  The paged bench exit-gates the fallback
-counter at zero.
-
-Scope of that guard: the ``except`` around the dispatch can only see
-errors raised while *this frame* runs — i.e. while the streamed call
-traces.  When ``paged_attention`` runs inside an outer jit closure
-(``layers.attention``), backend lowering and compilation happen after
-tracing returns, outside any except.  The dispatcher therefore
-**probe-compiles** the streamed kernel once per geometry (an AOT
-``.lower(...).compile()`` on abstract avals) before committing to the
-lane, so lowering/compile failures surface where the fallback can
-reroute.  A pure *runtime* fault on the final backend (e.g. a device
-OOM mid-execution) remains out of reach of any dispatcher-level guard
-— that residue is the documented limit of the contract.
+``crossstack_dispatch_total{path=paged_scratch|paged_streamed,
+geometry}`` — bumped per call, i.e. per trace under jit, the same
+accounting ``core/engine.matmul`` uses — so CI can pin which lane
+served each decode closure (``paged_path_calls`` is the summed view).
+**No fallback**: a lane that fails to trace, lower or compile raises
+to the caller; nothing reroutes to another lane or to a reference.
 
 Page tables may ALIAS: no validation here (or in the kernels) assumes
 table entries are unique across rows.  Refcounted prefix sharing
@@ -49,10 +33,8 @@ in-contract".
 """
 from __future__ import annotations
 
-import warnings
 from collections.abc import Mapping
 
-import jax
 import jax.numpy as jnp
 
 from repro import obs
@@ -60,36 +42,6 @@ from repro.kernels.paged_attention import kernel as _kernel_mod
 from repro.kernels.paged_attention.kernel import paged_attention_kernel
 
 _DISPATCH = "crossstack_dispatch_total"
-
-# streamed-lane failures already warned, keyed by geometry — warn once
-# per geometry, not once per traced closure
-_FALLBACK_WARNED = set()
-
-# streamed-kernel geometries whose AOT probe-compile succeeded: the
-# expensive .lower().compile() runs once per geometry, then dispatch is
-# a set lookup
-_PROBE_OK = set()
-
-
-def _probe_streamed(q, k_pages, v_pages, page_table, kv_len, q_offset,
-                    *, causal, interpret, block_pages) -> None:
-    """AOT-compile the streamed kernel for this geometry (abstract
-    avals — nothing executes) so lowering/compile failures raise HERE,
-    inside the dispatcher's try, instead of later when the enclosing
-    jit closure compiles outside any except.  Safe to call from within
-    an outer trace: ``.lower`` spawns an independent trace."""
-    key = tuple((tuple(x.shape), jnp.dtype(x.dtype).name)
-                for x in (q, k_pages, page_table)) + (
-                    causal, interpret, block_pages)
-    if key in _PROBE_OK:
-        return
-    aval = lambda x: jax.ShapeDtypeStruct(tuple(x.shape),  # noqa: E731
-                                          jnp.dtype(x.dtype))
-    _kernel_mod.paged_attention_streamed.lower(
-        aval(q), aval(k_pages), aval(v_pages), aval(page_table),
-        aval(kv_len), aval(q_offset), causal=causal, interpret=interpret,
-        block_pages=block_pages).compile()
-    _PROBE_OK.add(key)
 
 
 def _count_dispatch(path: str, p_seq: int, ps: int) -> None:
@@ -105,7 +57,7 @@ class _PagedPathCallsView(Mapping):
     across geometries (``paged_path_calls["paged_streamed"]``); the
     registry keeps the per-geometry split."""
 
-    _PATHS = ("paged_scratch", "paged_streamed", "paged_fallback")
+    _PATHS = ("paged_scratch", "paged_streamed")
 
     def __getitem__(self, key: str) -> int:
         if key not in self._PATHS:
@@ -133,9 +85,8 @@ paged_path_calls = _PagedPathCallsView()
 
 
 def paged_attention(q, k_pages, v_pages, page_table, kv_len, q_offset,
-                    *, causal: bool = True, interpret: bool = True,
-                    lane: str = "auto", stream_min_pages: int = 0,
-                    block_pages: int = 16):
+                    *, causal: bool = True, lane: str = "auto",
+                    stream_min_pages: int = 0, block_pages: int = 16):
     """Ragged paged decode attention; see kernel.py for the per-lane
     contracts.
 
@@ -170,39 +121,11 @@ def paged_attention(q, k_pages, v_pages, page_table, kv_len, q_offset,
                 and p_seq >= stream_min_pages else "scratch")
     page_table = page_table.astype(jnp.int32)
     if lane == "streamed":
-        try:
-            # probe-compile first: trace-time errors raise from the call
-            # below, but lowering/compile errors would otherwise fire
-            # later, inside the enclosing jit's compile, past this except
-            # (module docstring, "Scope of that guard")
-            _probe_streamed(q, k_pages, v_pages, page_table, kv_len,
-                            q_offset, causal=causal, interpret=interpret,
-                            block_pages=block_pages)
-            out = _kernel_mod.paged_attention_streamed(
-                q, k_pages, v_pages, page_table, kv_len, q_offset,
-                causal=causal, interpret=interpret,
-                block_pages=block_pages)
-            _count_dispatch("paged_streamed", p_seq, ps)
-            return out
-        except Exception as e:  # noqa: BLE001 — trace/lower/compile failure
-            # NEVER silently degrade: the fallback target is the scratch
-            # KERNEL (still a Pallas lane, still bitwise-contracted), the
-            # warning names the cause, and the counter lets the bench
-            # exit-gate fallbacks at zero.
-            key = (p_seq, ps)
-            if key not in _FALLBACK_WARNED:
-                _FALLBACK_WARNED.add(key)
-                warnings.warn(
-                    f"paged_attention: streamed lane failed for geometry "
-                    f"{p_seq}x{ps} ({type(e).__name__}: {e}); falling "
-                    f"back to the gather-scratch kernel. Long windows "
-                    f"will pay O(window) VMEM until this is fixed.",
-                    stacklevel=2)
-            _count_dispatch("paged_fallback", p_seq, ps)
-            return paged_attention_kernel(q, k_pages, v_pages, page_table,
-                                          kv_len, q_offset, causal=causal,
-                                          interpret=interpret)
+        out = _kernel_mod.paged_attention_streamed(
+            q, k_pages, v_pages, page_table, kv_len, q_offset,
+            causal=causal, block_pages=block_pages)
+        _count_dispatch("paged_streamed", p_seq, ps)
+        return out
     _count_dispatch("paged_scratch", p_seq, ps)
     return paged_attention_kernel(q, k_pages, v_pages, page_table, kv_len,
-                                  q_offset, causal=causal,
-                                  interpret=interpret)
+                                  q_offset, causal=causal)

@@ -75,6 +75,7 @@ import jax.numpy as jnp
 
 from repro import obs
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.model import build_model
 from repro.serve.engine import BatchScheduler, Request
 
@@ -250,6 +251,7 @@ def main(argv=None):
         raise SystemExit("--stream-pages routes the paged-attention "
                          "kernel; it requires --kv paged")
     mode_policy = parse_mode_policy(args.mode_policy)
+    enable_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if cfg.family in ("encdec", "vlm", "rwkv6", "zamba2"):
@@ -429,8 +431,7 @@ def main(argv=None):
               f"{rep['pages_per_seq']}p table, "
               f"block={rep['block_pages']}p; traced closures "
               f"scratch={d['paged_scratch']} "
-              f"streamed={d['paged_streamed']} "
-              f"fallback={d['paged_fallback']}")
+              f"streamed={d['paged_streamed']}")
     if (args.prefix_share or args.preemption) and sched.metrics.enabled:
         reg = sched.metrics
         if args.prefix_share:

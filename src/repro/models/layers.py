@@ -131,7 +131,6 @@ class AttnConfig:
     q_chunk: int = 0            # 0 = unchunked; else chunk the query axis
     chunk_unroll: bool = True   # unroll the q-chunk loop (see DESIGN §5)
     paged_kernel: bool = False  # paged decode via the Pallas kernel
-    kernel_interpret: bool = True  # Pallas interpret mode (CPU container)
     paged_stream_pages: int = 0  # stream the paged kernel (online-softmax
     # block lane) when the page table is >= this many pages; 0 = always
     # the gather-scratch lane (the bitwise small-window fast path)
@@ -298,7 +297,6 @@ def attention(p, cfg: AttnConfig, x, positions, cache=None,
             from repro.kernels.paged_attention import paged_attention
             out = paged_attention(q, ck, cv, pt, new_len, pos,
                                   causal=cfg.causal,
-                                  interpret=cfg.kernel_interpret,
                                   stream_min_pages=cfg.paged_stream_pages,
                                   block_pages=cfg.paged_block_pages)
         else:

@@ -80,6 +80,68 @@ def greedy_generate(model: Model, params, batch, max_new: int,
                            axis=1)[:, :, 0]
 
 
+def make_window_step(model: Model, tenant: str = "A") -> Callable:
+    """The ONE jitted window closure a scheduler lane runs:
+    ``(params, tiles, tokens, cache, m, leak) -> (token, cache)``.
+
+    ``tokens`` is the fixed (width, chunk) window; ``m`` the per-row
+    valid counts (chunk tokens for a row mid-prompt, 1 for a decoding
+    row, 0 for an empty slot).  The cache fill marker is pinned to
+    ``old_len + m`` — pad positions past a row's count are never
+    attendable (causal + length masks hit them with exact -1e30s), so
+    the emitted token at row position ``m - 1`` is bit-exact with an
+    unpadded reference.  Because the compiled shape is prompt-length
+    independent, this closure traces exactly once per tenant for ANY
+    prompt mix.  The cache (argument 3) is donated.
+
+    ``tiles`` is the tenant's resident planes
+    (``CrossbarExecutor.resident_tiles``; ``{}`` on the digital
+    backend).  The body reads them under ``tile_scope``, so the
+    compiled step carries no tile constants: its program text and its
+    device footprint do not grow with the bytes programmed.
+
+    ``leak`` is the write-plane leakage of an in-flight hot-swap as a
+    *traced* scalar: the same compiled step serves leak = 0.0 in steady
+    state and the live value during an overlap window — no re-trace at
+    window boundaries, and (with ``cfg.use_kernel``) the Pallas kernel
+    applies it pre-ADC, so overlap decode never falls back to the
+    reference scan.  The digital backend ignores it.
+    """
+    ex = model.executor
+    n_traces = [0]
+
+    def _note_trace():
+        # host-side code in a jitted body runs at trace time only: each
+        # call here is exactly one (re)trace of THIS closure.  Any trace
+        # beyond the first is a re-trace — the runtime counter behind
+        # the "zero re-traces at swap-window boundaries" invariant
+        # (closure rebuilds at promotion get a fresh counter, so their
+        # first trace is expected).
+        n_traces[0] += 1
+        obs.note_jit_trace("decode", tenant, retrace=n_traces[0] > 1)
+
+    def _window(params, tokens, cache, m):
+        old = cache["layers"]["len"]                     # (L, B)
+        logits, cache = model.decode_step(params, tokens, cache)
+        layers = dict(cache["layers"])
+        layers["len"] = (old + m[None, :]).astype(old.dtype)
+        sel = jnp.take_along_axis(
+            logits, jnp.maximum(m - 1, 0)[:, None, None], axis=1)[:, 0]
+        tok = jnp.argmax(sel.astype(jnp.float32),
+                         axis=-1).astype(jnp.int32)
+        return tok, dict(cache, layers=layers)
+
+    def window_step(params, tiles, tokens, cache, m, leak):
+        _note_trace()
+        if ex is None:
+            return _window(params, tokens, cache, m)
+        with (ex.read_tenant(tenant), ex.leak_scope(leak),
+              ex.tile_scope(tiles)):
+            return _window(params, tokens, cache, m)
+
+    return jax.jit(window_step, donate_argnums=(3,))
+
+
 @dataclasses.dataclass
 class Request:
     rid: int
@@ -120,8 +182,8 @@ class Request:
 @dataclasses.dataclass
 class _Lane:
     """One tenant's serving state: a fixed slot batch against one plane
-    set, with its own jitted window closure (the tiles it traced are that
-    tenant's planes — trace constants, like params sharding)."""
+    set, with its own jitted window closure (``make_window_step``: the
+    tenant's planes are an argument of every call, like ``params``)."""
     tenant: str
     params: Any
     slots: List[Optional[Request]]
@@ -221,7 +283,7 @@ class BatchScheduler:
     up to ``stack_planes`` checkpoints from the plane bank of ONE
     crossbar executor: each tenant gets its own slot partition, cache,
     pool, and jitted closure (traced under ``executor.read_tenant(t)``
-    so the closure's trace constants are that tenant's planes), and
+    and fed that tenant's planes as an argument each step), and
     every ``step`` interleaves all token streams.  Requests route by
     ``Request.model_id``.
 
@@ -409,7 +471,7 @@ class BatchScheduler:
             cache = self.model.init_cache(n, self.max_len)
         return _Lane(tenant=tenant, params=params,
                      slots=[None] * n, cache=cache,
-                     queue=[], decode=self._make_decode(tenant),
+                     queue=[], decode=make_window_step(self.model, tenant),
                      pool=pool, width=n, n_slots=n,
                      weight=self._weights.get(tenant, 1.0),
                      device_cost=(ex.device_token_cost(tenant)
@@ -420,67 +482,6 @@ class BatchScheduler:
         ties (so the equal-weight order is the historical sorted one)."""
         return sorted(self._lanes,
                       key=lambda t: (-self._lanes[t].weight, t))
-
-    def _make_decode(self, tenant: str) -> Callable:
-        """The ONE jitted closure per tenant:
-        ``(params, tokens, cache, m, leak) -> (token, cache)``.
-
-        ``tokens`` is the fixed (width, chunk) window; ``m`` the per-row
-        valid counts (chunk tokens for a row mid-prompt, 1 for a
-        decoding row, 0 for an empty slot).  The cache fill marker is
-        pinned to ``old_len + m`` — pad positions past a row's count are
-        never attendable (causal + length masks hit them with exact
-        -1e30s), so the emitted token at row position ``m - 1`` is
-        bit-exact with an unpadded reference.  Because the compiled
-        shape is prompt-length independent, this closure traces exactly
-        once per tenant for ANY prompt mix.
-
-        ``leak`` is the write-plane leakage of an in-flight hot-swap as
-        a *traced* scalar: the same compiled step serves leak = 0.0 in
-        steady state and the live value during an overlap window — no
-        re-trace at window boundaries, and (with ``cfg.use_kernel``) the
-        Pallas kernel applies it pre-ADC, so overlap decode never falls
-        back to the reference scan."""
-        model = self.model
-        ex = model.executor
-        n_traces = [0]
-
-        def _note_trace():
-            # host-side code in a jitted body runs at trace time only:
-            # each call here is exactly one (re)trace of THIS closure.
-            # Any trace beyond the first is a re-trace — the runtime
-            # counter behind the "zero re-traces at swap-window
-            # boundaries" invariant (closure rebuilds at promotion get
-            # a fresh counter, so their first trace is expected).
-            n_traces[0] += 1
-            obs.note_jit_trace("decode", tenant, retrace=n_traces[0] > 1)
-
-        def _window(params, tokens, cache, m):
-            old = cache["layers"]["len"]                     # (L, B)
-            logits, cache = model.decode_step(params, tokens, cache)
-            layers = dict(cache["layers"])
-            layers["len"] = (old + m[None, :]).astype(old.dtype)
-            sel = jnp.take_along_axis(
-                logits, jnp.maximum(m - 1, 0)[:, None, None], axis=1)[:, 0]
-            tok = jnp.argmax(sel.astype(jnp.float32),
-                             axis=-1).astype(jnp.int32)
-            return tok, dict(cache, layers=layers)
-
-        if ex is None:
-            def digital_step(params, tokens, cache, m):
-                _note_trace()
-                return _window(params, tokens, cache, m)
-
-            digital = jax.jit(digital_step, donate_argnums=(2,))
-            return lambda params, tokens, cache, m, leak: digital(
-                params, tokens, cache, m)
-
-        def tenant_step(params, tokens, cache, m, leak):
-            _note_trace()
-            with ex.read_tenant(tenant), ex.leak_scope(leak):
-                return _window(params, tokens, cache, m)
-
-        return jax.jit(tenant_step, donate_argnums=(2,))
 
     @property
     def params(self):
@@ -586,9 +587,9 @@ class BatchScheduler:
         return self._swap is not None
 
     def _apply_promotion(self, tenant: str, new_params) -> None:
-        """Land promoted params on a lane: resident planes are trace
-        constants of the jitted closure, so the tenant's window closure
-        rebuilds (one re-trace, zero dropped requests).  A tenant
+        """Land promoted params on a lane: the tenant's window closure
+        rebuilds (one fresh trace, zero dropped requests) and its next
+        step reads the promoted planes as arguments.  A tenant
         deployed live via ``begin_hot_swap(..., tenant=...)`` gets a
         fresh lane here and starts admitting."""
         lane = self._lanes.get(tenant)
@@ -615,7 +616,7 @@ class BatchScheduler:
             self._lanes[tenant] = self._make_lane(tenant, new_params)
         else:
             lane.params = new_params
-            lane.decode = self._make_decode(tenant)
+            lane.decode = make_window_step(self.model, tenant)
             lane.paused = False
             ex = self.model.executor
             if ex is not None:
@@ -882,6 +883,7 @@ class BatchScheduler:
         finished: List[Request] = []
         decoded = False
         leak = self._leak_now()
+        ex = self.model.executor
         c = self.chunk
         for t in self._lane_order():
             lane = self._lanes[t]
@@ -915,9 +917,11 @@ class BatchScheduler:
                     toks[i, 0] = req.out[-1]
                     m[i] = 1
                     emit[i] = "decode"
+            # the tenant's read-active planes ride as an argument
+            tiles = ex.resident_tiles(t) if ex is not None else {}
             t0 = self.tracer.now()
             tok, lane.cache = lane.decode(
-                lane.params, jnp.asarray(toks), lane.cache,
+                lane.params, tiles, jnp.asarray(toks), lane.cache,
                 jnp.asarray(m), leak)
             decoded = True
             tok_host = np.asarray(tok)
@@ -982,8 +986,7 @@ class BatchScheduler:
         are the process-global trace-time counters
         (``crossstack_dispatch_total{path=paged_*}``): one bump per
         traced closure, so a serving run whose every closure streamed
-        shows ``paged_fallback == 0`` and ``paged_streamed >= 1`` — the
-        long-context bench's no-silent-fallback exit gate reads this.
+        shows ``paged_streamed >= 1`` and no ``paged_scratch`` bump.
         """
         from repro.kernels.paged_attention import paged_path_calls
         cfg = self.model.cfg

@@ -24,7 +24,7 @@ def test_kernel_matches_ref_sweeps(n, m, sweeps):
     vr = jnp.broadcast_to(v_in[:, None], (n, m)).astype(jnp.float32)
     vc = jnp.zeros((n, m), jnp.float32)
     kr, kc = jacobi_sweeps(g, v_in[:, None], vr, vc, g_w=float(g_w),
-                           sweeps=sweeps, interpret=True)
+                           sweeps=sweeps)
     rr, rc = vr, vc
     for _ in range(sweeps):
         rr, rc = jacobi_sweep_ref(rr, rc, g, v_in, g_w, 1.0)

@@ -48,8 +48,8 @@ def test_crossbar_mac_matches_ref(b, k, n, s, ib, ab, bpc, rpa):
     neg = _codes(k3, (s, k, n), base)
     kw = dict(in_bits=ib, adc_bits=ab, bits_per_cell=bpc, rows_per_adc=rpa)
     ref = crossbar_mac_ref(x_int, pos, neg, **kw)
-    out = crossbar_mac(x_int, pos, neg, block_b=min(b, 8), block_n=min(n, 32),
-                       interpret=True, **kw)
+    out = crossbar_mac(x_int, pos, neg, block_b=min(b, 8),
+                       block_n=min(n, 32), **kw)
     # tolerance: one ADC LSB accumulated per row-group and slice
     lsb = rpa * (base - 1) / (2.0 ** ab - 1.0)
     tol = lsb * (k // rpa) * s * 4 + 1e-3
@@ -71,8 +71,7 @@ def test_deepnet_stream_matches_ref(b, k, n, dtype):
               rows_per_adc=32)
     ref = deepnet_stream_ref(x_int, w.astype(jnp.float32), ws, **kw)
     out = deepnet_stream(x_int, w.astype(jnp.float32), ws.astype(jnp.float32),
-                         block_b=min(b, 8), block_n=min(n, 32),
-                         interpret=True, **kw)
+                         block_b=min(b, 8), block_n=min(n, 32), **kw)
     assert jnp.max(jnp.abs(out - ref)) <= 0.05
 
 
@@ -127,7 +126,7 @@ def test_crossbar_mac_leak_parity_vs_ref(leak_lsb, ib, ab, bpc):
     kw = dict(in_bits=ib, adc_bits=ab, bits_per_cell=bpc, rows_per_adc=rpa)
     ref = crossbar_mac_ref(x_int, pos, neg, leak_codes=leak, **kw)
     out = crossbar_mac(x_int, pos, neg, leak, block_b=min(b, 8),
-                       block_n=min(n, 32), interpret=True, **kw)
+                       block_n=min(n, 32), **kw)
     tol = lsb * (k // rpa) * s * 4 + 1e-3
     assert jnp.max(jnp.abs(out - ref)) <= tol
 

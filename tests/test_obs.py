@@ -234,7 +234,7 @@ def test_retrace_counter_increments_on_forced_retrace():
     lane = sched._lanes["A"]
     # batch-of-1 call against the width-traced window closure: new
     # shape, same built closure -> jit re-traces it
-    lane.decode(lane.params, jnp.zeros((1, sched.chunk), jnp.int32),
+    lane.decode(lane.params, {}, jnp.zeros((1, sched.chunk), jnp.int32),
                 model.init_cache(1, 24), jnp.ones((1,), jnp.int32),
                 jnp.float32(0.0))
     after = reg.total("serve_jit_retraces_total", closure="decode")

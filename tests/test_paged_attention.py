@@ -58,7 +58,7 @@ def test_paged_kernel_bit_exact_vs_ref_and_dense(b, sq, hq, kv, hd, ps,
     q, kp, vp, pt, kv_len, q_off = _case(key, b, sq, hq, kv, hd, ps, ppr,
                                          n_pages=b * ppr, dtype=jnp.bfloat16)
     ref = paged_attention_ref(q, kp, vp, pt, kv_len, q_off)
-    ker = paged_attention(q, kp, vp, pt, kv_len, q_off, interpret=True)
+    ker = paged_attention(q, kp, vp, pt, kv_len, q_off)
     assert jnp.array_equal(ker, ref)
     cfg = AttnConfig(d_model=hq * hd, n_heads=hq, n_kv=kv, head_dim=hd)
     gk = gather_pages(kp, pt)
@@ -75,7 +75,7 @@ def test_paged_kernel_dtypes(dtype):
     q, kp, vp, pt, kv_len, q_off = _case(jax.random.PRNGKey(7), 2, 4, 4,
                                          2, 8, 8, 3, n_pages=6, dtype=dtype)
     ref = paged_attention_ref(q, kp, vp, pt, kv_len, q_off)
-    ker = paged_attention(q, kp, vp, pt, kv_len, q_off, interpret=True)
+    ker = paged_attention(q, kp, vp, pt, kv_len, q_off)
     assert ker.dtype == dtype
     assert jnp.array_equal(ker, ref)
 
@@ -91,7 +91,7 @@ def test_paged_kernel_fp8_cache_upcasts_like_dense_path():
     kp8 = kp.astype(jnp.float8_e4m3fn)
     vp8 = vp.astype(jnp.float8_e4m3fn)
     ref = paged_attention_ref(q, kp8, vp8, pt, kv_len, q_off)
-    ker = paged_attention(q, kp8, vp8, pt, kv_len, q_off, interpret=True)
+    ker = paged_attention(q, kp8, vp8, pt, kv_len, q_off)
     assert ker.dtype == q.dtype
     assert jnp.array_equal(ker, ref)
     cfg = AttnConfig(d_model=4 * 8, n_heads=4, n_kv=2, head_dim=8)
@@ -107,11 +107,10 @@ def test_null_page_contents_never_leak_into_output():
     q, kp, vp, pt, kv_len, q_off = _case(jax.random.PRNGKey(11), 2, 4, 4,
                                          2, 8, 8, 3, n_pages=6,
                                          dtype=jnp.bfloat16)
-    clean = paged_attention(q, kp, vp, pt, kv_len, q_off, interpret=True)
+    clean = paged_attention(q, kp, vp, pt, kv_len, q_off)
     kp_p = kp.at[0].set(jnp.asarray(1e4, kp.dtype))
     vp_p = vp.at[0].set(jnp.asarray(-1e4, vp.dtype))
-    poisoned = paged_attention(q, kp_p, vp_p, pt, kv_len, q_off,
-                               interpret=True)
+    poisoned = paged_attention(q, kp_p, vp_p, pt, kv_len, q_off)
     assert jnp.array_equal(clean, poisoned)
 
 
@@ -136,8 +135,7 @@ def test_aliased_page_tables_bit_exact_with_materialized_copies():
     pt_mat = jnp.asarray([[1, 2, 3], [5, 6, 4]], jnp.int32)
     kv_len = jnp.asarray([ps * 3, ps * 3 - 2], jnp.int32)
     q_off = kv_len - sq
-    for fn in (paged_attention_ref,
-               lambda *a: paged_attention(*a, interpret=True)):
+    for fn in (paged_attention_ref, paged_attention):
         aliased = fn(q, kp, vp, pt_alias, kv_len, q_off)
         materialized = fn(q, kp_mat, vp_mat, pt_mat, kv_len, q_off)
         assert jnp.array_equal(aliased, materialized)

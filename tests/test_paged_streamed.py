@@ -1,6 +1,6 @@
 """Streamed-lane paged attention CI: the bounded-ulp / argmax-stable
 contract of the block-streamed online-softmax kernel, the two-lane
-dispatch (counters, no-silent-fallback), the compat shim, and the
+dispatch (counters, failures raise), and the
 O(page_block) VMEM claim.
 
 The scratch lane keeps its BITWISE tripod in test_paged_attention.py;
@@ -12,18 +12,16 @@ this file pins everything the streamed lane adds:
     documented tolerance AND argmax-stable,
   * streamed kernel == its same-schedule jnp flash oracle (tight),
   * aliased/COW page tables are bitwise-invisible to the streamed lane,
-  * the compat fallback grid runs the identical kernel body bitwise,
   * dispatch counters: auto-lane thresholding, and a streamed-lane
-    failure warns ONCE, counts paged_fallback, and lands on the scratch
-    KERNEL (never the jnp reference scan),
+    failure raises to the caller (no fallback lane, nothing counted),
   * streamed-lane VMEM scratch is constant in the window length while
     the scratch lane's grows linearly,
   * scheduler property: a long-prompt admission on the streamed lane
     causes ZERO retraces (runtime serve_jit_retraces_total check) and
-    zero fallbacks, with token streams matching the scratch lane.
+    streamed dispatches only, with token streams matching the scratch
+    lane.
 """
 import dataclasses
-import warnings
 
 import jax
 import jax.numpy as jnp
@@ -36,7 +34,6 @@ from repro.kernels.paged_attention import (
     paged_path_calls, resolve_block_pages, scratch_lane_vmem_bytes,
     streamed_lane_resident_bytes, streamed_lane_vmem_bytes)
 from repro.kernels.paged_attention import ops as paged_ops
-from repro.kernels.paged_attention.kernel import paged_attention_kernel
 from repro.kernels.paged_attention.ref import gather_pages
 from repro.models.layers import AttnConfig, _chunked_sdpa
 
@@ -172,29 +169,6 @@ def test_streamed_aliased_page_tables_bitwise_vs_materialized():
     assert jnp.array_equal(aliased, materialized)
 
 
-def test_compat_fallback_grid_is_bitwise_identical():
-    """The prefetch_grid_spec fallback (plain grid, scalars as constant
-    full-array operands) runs the IDENTICAL kernel body: outputs match
-    the PrefetchScalarGridSpec path bitwise."""
-    q, kp, vp, pt, kv_len, q_off = _window_case(
-        jax.random.PRNGKey(7), (1, 9, 32), ps=_PS, p_seq=8,
-        dtype=jnp.float32)
-    primary = paged_attention_streamed(q, kp, vp, pt, kv_len, q_off,
-                                       block_pages=2)
-    fallback = paged_attention_streamed(q, kp, vp, pt, kv_len, q_off,
-                                        block_pages=2,
-                                        force_compat_fallback=True)
-    assert jnp.array_equal(primary, fallback)
-
-
-def test_compat_prefetch_spec_validates_scalar_shapes():
-    from repro.kernels.compat import prefetch_grid_spec
-    with pytest.raises(ValueError, match="scalar_shapes"):
-        prefetch_grid_spec(num_scalar_prefetch=2, grid=(1,), in_specs=[],
-                           out_specs=None, scratch_shapes=[],
-                           scalar_shapes=[(1, 1)])
-
-
 def test_resolve_block_pages_clamps_to_divisor():
     assert resolve_block_pages(16, 16) == 16
     assert resolve_block_pages(16, 5) == 4
@@ -204,7 +178,7 @@ def test_resolve_block_pages_clamps_to_divisor():
     assert resolve_block_pages(1, 16) == 1
 
 
-# -- dispatch: counters, auto lane, no silent fallback ------------------------
+# -- dispatch: counters, auto lane, failures raise ----------------------------
 
 def _tiny_case(seed=1):
     return _window_case(jax.random.PRNGKey(seed), (3, 14), ps=_PS,
@@ -214,7 +188,7 @@ def _tiny_case(seed=1):
 def test_auto_lane_thresholds_on_table_width():
     """lane="auto" picks streamed iff stream_min_pages is enabled and
     the table is at least that wide; every call lands in the dispatch
-    counters with zero fallbacks."""
+    counters."""
     obs.reset()
     q, kp, vp, pt, kv_len, q_off = _tiny_case()
     base = dict(paged_path_calls)
@@ -228,17 +202,15 @@ def test_auto_lane_thresholds_on_table_width():
     paged_attention(q, kp, vp, pt, kv_len, q_off, lane="streamed",
                     block_pages=2)
     assert paged_path_calls["paged_streamed"] == base["paged_streamed"] + 2
-    assert paged_path_calls["paged_fallback"] == base["paged_fallback"]
     with pytest.raises(ValueError, match="lane"):
         paged_attention(q, kp, vp, pt, kv_len, q_off, lane="warp")
 
 
 def test_streamed_failure_warns_once_and_falls_back_to_scratch_kernel(
         monkeypatch):
-    """The no-silent-fallback contract: a streamed-lane failure warns
-    ONCE per geometry, bumps paged_fallback, and routes to the scratch
-    KERNEL — the output is bitwise the scratch lane's, never a
-    reference-scan approximation."""
+    """Fails loudly: a streamed-lane failure propagates to the caller
+    every time and counts no dispatch, so a lane that cannot lower on
+    the chip stops the program instead of serving another lane."""
     obs.reset()
     q, kp, vp, pt, kv_len, q_off = _tiny_case(seed=2)
 
@@ -247,20 +219,11 @@ def test_streamed_failure_warns_once_and_falls_back_to_scratch_kernel(
 
     monkeypatch.setattr(paged_ops._kernel_mod, "paged_attention_streamed",
                         boom)
-    monkeypatch.setattr(paged_ops, "_FALLBACK_WARNED", set())
     base = dict(paged_path_calls)
-    with pytest.warns(UserWarning, match="streamed lane failed"):
-        out1 = paged_attention(q, kp, vp, pt, kv_len, q_off,
-                               lane="streamed")
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")       # second call must NOT warn
-        out2 = paged_attention(q, kp, vp, pt, kv_len, q_off,
-                               lane="streamed")
-    scratch = paged_attention_kernel(q, kp, vp, pt, kv_len, q_off)
-    assert jnp.array_equal(out1, scratch)
-    assert jnp.array_equal(out2, scratch)
-    assert paged_path_calls["paged_fallback"] == base["paged_fallback"] + 2
-    assert paged_path_calls["paged_streamed"] == base["paged_streamed"]
+    for _ in range(2):
+        with pytest.raises(RuntimeError, match="induced streamed-lane"):
+            paged_attention(q, kp, vp, pt, kv_len, q_off, lane="streamed")
+    assert dict(paged_path_calls) == base
 
 
 # -- the O(page_block) VMEM claim ---------------------------------------------
@@ -341,6 +304,6 @@ def test_streamed_lane_long_prompt_admission_zero_retraces():
     assert reg.total("serve_jit_traces_total", closure="decode",
                      tenant="A") == 1
     assert paged_path_calls["paged_streamed"] > base["paged_streamed"]
-    assert paged_path_calls["paged_fallback"] == base["paged_fallback"]
+    assert paged_path_calls["paged_scratch"] == base["paged_scratch"]
     scratch = serve()                        # default config: scratch lane
     assert streamed == scratch               # argmax-stable end to end
