@@ -1,0 +1,98 @@
+"""What one cell is, read from data files by name.
+
+``BENCHMARK.json`` names each cell's configuration and traffic mix and
+lists the metrics; ``configs/<config>.json`` holds the model as it is
+run, ``mixes/<mix>.json`` the traffic parameters and
+``cells/<cell>.json`` the serving shape (slots, cache depth, chunk, page
+size and, for an open loop, the fixed rate).  Adding a cell adds files
+and entries; no code here names a cell.
+"""
+from __future__ import annotations
+
+import dataclasses
+import json
+from pathlib import Path
+from typing import Any, Dict, List
+
+HERE = Path(__file__).resolve().parent
+ROOT = HERE.parents[1]
+
+
+def load_json(path: Path) -> Dict[str, Any]:
+    with open(path) as f:
+        return json.load(f)
+
+
+@dataclasses.dataclass(frozen=True)
+class Cell:
+    name: str
+    config: Dict[str, Any]       # configs/<config>.json
+    mix: Dict[str, Any]          # mixes/<mix>.json
+    serve: Dict[str, Any]        # cells/<cell>.json
+    end_to_end: List[Dict[str, Any]]   # the cell's end-to-end metrics
+    per_layer: List[Dict[str, Any]]    # the cell's per-layer metrics
+    chips: int
+
+    @property
+    def open_loop(self) -> bool:
+        return self.mix["loop"] == "open"
+
+
+def _applies(metric: Dict[str, Any], cell: str) -> bool:
+    return "workloads" not in metric or cell in metric["workloads"]
+
+
+def load_cell(name: str, bench_path: Path = ROOT / "BENCHMARK.json",
+              base: Path = HERE) -> Cell:
+    """The cell ``name`` of ``BENCHMARK.json`` with its files."""
+    bench = load_json(bench_path)
+    entry = {w["name"]: w for w in bench["workloads"]}.get(name)
+    if entry is None:
+        raise KeyError(f"no workload {name!r} in {bench_path}")
+    serve = load_json(base / "cells" / f"{name}.json")
+    for key, want in (("config", entry["config"]),
+                      ("mix", entry["traffic"])):
+        if serve[key] != want:
+            raise ValueError(f"cells/{name}.json says {key} "
+                             f"{serve[key]!r}, BENCHMARK.json {want!r}")
+    return Cell(
+        name=name,
+        config=load_json(base / "configs" / f"{entry['config']}.json"),
+        mix=load_json(base / "mixes" / f"{entry['traffic']}.json"),
+        serve=serve,
+        end_to_end=[m for m in bench["end_to_end"] if _applies(m, name)],
+        per_layer=[m for m in bench["per_layer"] if _applies(m, name)],
+        chips=int(entry["chips"]))
+
+
+def model_config(conf: Dict[str, Any]):
+    """The program's ``ModelConfig`` for a configuration file."""
+    from repro.core.engine import EngineConfig
+    from repro.core.quant import QuantConfig
+    from repro.models.model import ModelConfig
+
+    if conf["model_type"] != "qwen3" or conf["hidden_act"] != "silu":
+        raise ValueError(f"{conf['name']}: only qwen3 with silu is wired")
+    if conf["rms_norm_eps"] != 1e-6:
+        raise ValueError("the program's RMSNorm epsilon is fixed at 1e-6")
+    serving = conf["serving"]
+    kw = dict(
+        name=conf["name"], family="dense",
+        n_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
+        n_heads=conf["num_attention_heads"],
+        n_kv=conf["num_key_value_heads"], head_dim=conf["head_dim"],
+        d_ff=conf["intermediate_size"], vocab=conf["vocab_size"],
+        act="swiglu", qk_norm=True, rope_theta=float(conf["rope_theta"]),
+        kv_repeat=serving["kv_repeat"],
+        tie_embeddings=conf["tie_word_embeddings"],
+        backend=serving["backend"])
+    if serving["backend"] == "crossbar":
+        xb = conf["crossbar"]
+        kw["xbar"] = EngineConfig(
+            tile_rows=xb["tile_rows"], tile_cols=xb["tile_cols"],
+            mode=xb["mode"], use_kernel=xb["use_kernel"],
+            quant=QuantConfig(w_bits=xb["w_bits"], in_bits=xb["in_bits"],
+                              adc_bits=xb["adc_bits"],
+                              bits_per_cell=xb["bits_per_cell"],
+                              per_channel=xb["per_channel"]))
+    return ModelConfig(**kw)
