@@ -138,4 +138,5 @@ def crossbar_mac(x_int, pos, neg, leak_codes=0.0, *, in_bits: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=kernels.interpret_mode(),
+        name="crossbar_mac",
     )(leak, x_int, pos, neg)

@@ -135,4 +135,5 @@ def deepnet_stream(x_int, w, w_scale, *, w_bits: int, in_bits: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=kernels.interpret_mode(),
+        name="deepnet_stream",
     )(x_int, w, w_scale)

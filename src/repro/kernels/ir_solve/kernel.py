@@ -69,4 +69,5 @@ def jacobi_sweeps(g, v_in, v_row, v_col, *, g_w: float, omega: float = 1.0,
                    pl.BlockSpec((n, m), lambda: (0, 0))),
         compiler_params=pltpu.CompilerParams(),
         interpret=kernels.interpret_mode(),
+        name="jacobi_sweeps",
     )(g, v_in, v_row, v_col)

@@ -158,6 +158,7 @@ def paged_attention_kernel(q, k_pages, v_pages, page_table, kv_len,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=kernels.interpret_mode(),
+        name="paged_attention_kernel",
     )(page_table,
       kv_len.astype(jnp.int32).reshape(b, 1),
       q_offset.astype(jnp.int32).reshape(b, 1),
@@ -296,6 +297,7 @@ def paged_attention_streamed(q, k_pages, v_pages, page_table, kv_len,
         out_shape=jax.ShapeDtypeStruct((b, sq, hq, hd), q.dtype),
         grid_spec=grid_spec,
         interpret=kernels.interpret_mode(),
+        name="paged_attention_streamed",
     )(page_table.astype(jnp.int32),
       kv_len.astype(jnp.int32).reshape(b, 1),
       q_offset.astype(jnp.int32).reshape(b, 1),

@@ -252,6 +252,43 @@ def _chunked_sdpa(q, k, v, cfg: AttnConfig, kv_len=None,
     return out.reshape(b, sq, *q.shape[2:])
 
 
+def _paged_attend(q, k, v, cfg: AttnConfig, cache):
+    """Paged decode: route this window's K/V writes through the page
+    table, then attend over the gathered logical view.  ``pt`` maps each
+    row's logical pages to physical pages of the pool arrays (leading
+    axis n_pages + 1); physical page 0 is the reserved null page —
+    unallocated entries point at it, so out-of-range writes land there
+    (the dense path's dropped-OOB-scatter semantics) and gathers through
+    it read only masked positions.  Returns (out, new_cache)."""
+    b, sq = q.shape[:2]
+    pos = cache["len"]                                    # (B,)
+    pt = cache["pt"]                                      # (B, P_seq)
+    ps = cache["k"].shape[1]
+    depth = pt.shape[1] * ps                              # == max_len
+    s_idx = pos[:, None] + jnp.arange(sq)[None]           # (B, sq)
+    inb = s_idx < depth
+    lpage = jnp.minimum(s_idx // ps, pt.shape[1] - 1)
+    phys = jnp.where(inb, jnp.take_along_axis(pt, lpage, axis=1), 0)
+    slot = jnp.where(inb, s_idx % ps, 0)
+    ck = cache["k"].at[phys, slot].set(k.astype(cache["k"].dtype))
+    cv = cache["v"].at[phys, slot].set(v.astype(cache["v"].dtype))
+    new_len = cache["len"] + sq
+    if cfg.paged_kernel:
+        from repro.kernels.paged_attention import paged_attention
+        out = paged_attention(q, ck, cv, pt, new_len, pos,
+                              causal=cfg.causal,
+                              stream_min_pages=cfg.paged_stream_pages,
+                              block_pages=cfg.paged_block_pages)
+    else:
+        # gather width is exactly max_len (page_size | max_len), so the
+        # SDPA below sees the same einsum shapes as the dense branch —
+        # the bit-exactness contract with that path
+        gk = ck[pt].reshape(b, depth, *ck.shape[2:])
+        gv = cv[pt].reshape(b, depth, *cv.shape[2:])
+        out = _chunked_sdpa(q, gk, gv, cfg, kv_len=new_len, q_offset=pos)
+    return out, {"k": ck, "v": cv, "len": new_len, "pt": pt}
+
+
 def attention(p, cfg: AttnConfig, x, positions, cache=None,
               cross_kv=None, kv_len=None):
     """Returns (y, new_cache).
@@ -260,67 +297,41 @@ def attention(p, cfg: AttnConfig, x, positions, cache=None,
       k, v: (B, S_max, kv_eff, hd) and "len": (B,) int32 fill marker.
     cross_kv: (k, v) precomputed for encoder-decoder cross attention.
     """
-    b, sq, _ = x.shape
+    sq = x.shape[1]
     if cross_kv is not None:
         q = _qkv_proj(x, p["wq"], "wq")
         if cfg.qk_norm:
             q = rmsnorm(q, p["q_norm"])
         k, v = cross_kv
-        out = _chunked_sdpa(q, k, v, cfg, kv_len=kv_len, cross=True)
-        new_cache = cache
-    elif cache is None:
-        q, k, v = _project_qkv(p, cfg, x, positions)
-        out = _chunked_sdpa(q, k, v, cfg)
-        new_cache = None
-    elif "pt" in cache:
-        # paged decode: route this window's K/V writes through the page
-        # table, then attend over the gathered logical view.  ``pt`` maps
-        # each row's logical pages to physical pages of the pool arrays
-        # (leading axis n_pages + 1); physical page 0 is the reserved
-        # null page — unallocated entries point at it, so out-of-range
-        # writes land there (the dense path's dropped-OOB-scatter
-        # semantics) and gathers through it read only masked positions.
-        q, k, v = _project_qkv(p, cfg, x, positions)
-        pos = cache["len"]                                # (B,)
-        pt = cache["pt"]                                  # (B, P_seq)
-        ps = cache["k"].shape[1]
-        depth = pt.shape[1] * ps                          # == max_len
-        s_idx = pos[:, None] + jnp.arange(sq)[None]       # (B, sq)
-        inb = s_idx < depth
-        lpage = jnp.minimum(s_idx // ps, pt.shape[1] - 1)
-        phys = jnp.where(inb, jnp.take_along_axis(pt, lpage, axis=1), 0)
-        slot = jnp.where(inb, s_idx % ps, 0)
-        ck = cache["k"].at[phys, slot].set(k.astype(cache["k"].dtype))
-        cv = cache["v"].at[phys, slot].set(v.astype(cache["v"].dtype))
-        new_len = cache["len"] + sq
-        if cfg.paged_kernel:
-            from repro.kernels.paged_attention import paged_attention
-            out = paged_attention(q, ck, cv, pt, new_len, pos,
-                                  causal=cfg.causal,
-                                  stream_min_pages=cfg.paged_stream_pages,
-                                  block_pages=cfg.paged_block_pages)
-        else:
-            # gather width is exactly max_len (page_size | max_len), so
-            # the SDPA below sees the same einsum shapes as the dense
-            # branch — the bit-exactness contract with that path
-            gk = ck[pt].reshape(b, depth, *ck.shape[2:])
-            gv = cv[pt].reshape(b, depth, *cv.shape[2:])
-            out = _chunked_sdpa(q, gk, gv, cfg, kv_len=new_len,
-                                q_offset=pos)
-        new_cache = {"k": ck, "v": cv, "len": new_len, "pt": pt}
     else:
-        # decode: append this step's K/V at each row's own fill position —
-        # slots admitted with different prompt lengths sit at different
-        # depths, so the write index and causal offset are per-row
         q, k, v = _project_qkv(p, cfg, x, positions)
-        pos = cache["len"]                                # (B,)
-        b_idx = jnp.arange(x.shape[0])[:, None]
-        s_idx = pos[:, None] + jnp.arange(sq)[None]
-        ck = cache["k"].at[b_idx, s_idx].set(k.astype(cache["k"].dtype))
-        cv = cache["v"].at[b_idx, s_idx].set(v.astype(cache["v"].dtype))
-        new_len = cache["len"] + sq
-        out = _chunked_sdpa(q, ck, cv, cfg, kv_len=new_len, q_offset=pos)
-        new_cache = {"k": ck, "v": cv, "len": new_len}
+    # the scope covers the K/V writes and gathers, scores, softmax and
+    # PV; the projections on either side stay outside it
+    with jax.named_scope("attention"):
+        if cross_kv is not None:
+            out = _chunked_sdpa(q, k, v, cfg, kv_len=kv_len, cross=True)
+            new_cache = cache
+        elif cache is None:
+            out = _chunked_sdpa(q, k, v, cfg)
+            new_cache = None
+        elif "pt" in cache:
+            out, new_cache = _paged_attend(q, k, v, cfg, cache)
+        else:
+            # decode: append this step's K/V at each row's own fill
+            # position — slots admitted with different prompt lengths
+            # sit at different depths, so the write index and causal
+            # offset are per-row
+            pos = cache["len"]                            # (B,)
+            b_idx = jnp.arange(x.shape[0])[:, None]
+            s_idx = pos[:, None] + jnp.arange(sq)[None]
+            ck = cache["k"].at[b_idx, s_idx].set(
+                k.astype(cache["k"].dtype))
+            cv = cache["v"].at[b_idx, s_idx].set(
+                v.astype(cache["v"].dtype))
+            new_len = cache["len"] + sq
+            out = _chunked_sdpa(q, ck, cv, cfg, kv_len=new_len,
+                                q_offset=pos)
+            new_cache = {"k": ck, "v": cv, "len": new_len}
     # explicit bf16 dot output: the TP partial-sum all-reduce then moves
     # bf16, not the f32 accumulator JAX requests by default (§Perf H1)
     y = crossbar_linear(
@@ -403,6 +414,7 @@ def mlp_init(key, d_model: int, d_ff: int, act: str):
     return p, s
 
 
+@jax.named_scope("mlp")
 def mlp(p, x, act: str):
     h = crossbar_linear(x, p["wi"], "wi",
                         digital=lambda: x @ p["wi"].astype(x.dtype))

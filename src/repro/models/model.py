@@ -323,8 +323,9 @@ def _build_transformer(cfg: ModelConfig) -> Model:
             cross_kv = _cross_kv(params, enc_out)
         h, new_layers, _ = _trunk(params, x, pos, caches=cache["layers"],
                                   cross_kv=cross_kv)
-        h = L.rmsnorm(h, params["ln_f"])
-        logits = _logits(params, h[:, -1:])
+        with jax.named_scope("head"):
+            h = L.rmsnorm(h, params["ln_f"])
+            logits = _logits(params, h[:, -1:])
         cache = dict(cache, layers=new_layers)
         if cfg.family == "encdec":
             cache["cross_kv"] = {"k": cross_kv[0].astype(cfg.dtype),
@@ -344,8 +345,9 @@ def _build_transformer(cfg: ModelConfig) -> Model:
         cross_kv = (ckv["k"], ckv["v"]) if ckv is not None else None
         h, new_layers, _ = _trunk(params, x, pos, caches=cache["layers"],
                                   cross_kv=cross_kv)
-        h = L.rmsnorm(h, params["ln_f"])
-        logits = _logits(params, h)
+        with jax.named_scope("head"):
+            h = L.rmsnorm(h, params["ln_f"])
+            logits = _logits(params, h)
         return logits, dict(cache, layers=new_layers)
 
     def _on_crossbar(fn):

@@ -9,10 +9,11 @@ Two tiers of ownership:
   (``serve_jit_traces_total`` / ``serve_jit_retraces_total``, bumped
   from inside jitted closure bodies, i.e. at trace time only).
 * **Per-scheduler registry/tracer** (``BatchScheduler.metrics`` /
-  ``.tracer``) — request lifecycle, token latency, QoS shares, and
-  modeled device-time/energy, scoped so concurrent schedulers in one
-  process (every bench builds several) never cross-contaminate and a
-  ``telemetry=False`` scheduler is a clean metrics-off baseline.
+  ``.tracer``) — request lifecycle, window and head fill, QoS shares,
+  and the Table-I model of array time/energy, scoped so concurrent
+  schedulers in one process (every bench builds several) never
+  cross-contaminate and a ``telemetry=False`` scheduler is a clean
+  metrics-off baseline.
 
 See ``docs/OBSERVABILITY.md`` for the metric/span catalog.
 """

@@ -17,6 +17,7 @@ allocation at admission and reclaim at completion.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Any, Callable, Dict, List, Optional
@@ -24,11 +25,14 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro import obs
 from repro.models.model import Model
 from repro.serve.hotswap import HotSwapper, overlap_report
 from repro.serve.kv_pool import PagedKVPool, default_pool_pages
+
+_NO_SPAN = contextlib.nullcontext()
 
 
 def make_prefill_step(model: Model):
@@ -100,6 +104,11 @@ def make_window_step(model: Model, tenant: str = "A") -> Callable:
     compiled step carries no tile constants: its program text and its
     device footprint do not grow with the bytes programmed.
 
+    The body runs under ``jax.named_scope("window")``, the row select
+    and argmax under ``sample``; the model adds ``attention``, ``mlp``
+    and ``head``.  Scopes name the compiled ops (HLO ``op_name``
+    metadata) for the profiler; they change nothing else.
+
     ``leak`` is the write-plane leakage of an in-flight hot-swap as a
     *traced* scalar: the same compiled step serves leak = 0.0 in steady
     state and the live value during an overlap window — no re-trace at
@@ -121,15 +130,18 @@ def make_window_step(model: Model, tenant: str = "A") -> Callable:
         obs.note_jit_trace("decode", tenant, retrace=n_traces[0] > 1)
 
     def _window(params, tokens, cache, m):
-        old = cache["layers"]["len"]                     # (L, B)
-        logits, cache = model.decode_step(params, tokens, cache)
-        layers = dict(cache["layers"])
-        layers["len"] = (old + m[None, :]).astype(old.dtype)
-        sel = jnp.take_along_axis(
-            logits, jnp.maximum(m - 1, 0)[:, None, None], axis=1)[:, 0]
-        tok = jnp.argmax(sel.astype(jnp.float32),
-                         axis=-1).astype(jnp.int32)
-        return tok, dict(cache, layers=layers)
+        with jax.named_scope("window"):
+            old = cache["layers"]["len"]                 # (L, B)
+            logits, cache = model.decode_step(params, tokens, cache)
+            layers = dict(cache["layers"])
+            layers["len"] = (old + m[None, :]).astype(old.dtype)
+            with jax.named_scope("sample"):
+                sel = jnp.take_along_axis(
+                    logits, jnp.maximum(m - 1, 0)[:, None, None],
+                    axis=1)[:, 0]
+                tok = jnp.argmax(sel.astype(jnp.float32),
+                                 axis=-1).astype(jnp.int32)
+            return tok, dict(cache, layers=layers)
 
     def window_step(params, tiles, tokens, cache, m, leak):
         _note_trace()
@@ -325,8 +337,9 @@ class BatchScheduler:
         self.pages_per_seq = (max_len // page_size if kv == "paged"
                               else 0)
         self.mode_policy = mode_policy
-        # per-scheduler telemetry: request lifecycle, token latency, QoS
-        # shares, modeled device time/energy.  Scoped per instance so
+        # per-scheduler telemetry: request lifecycle, window and head
+        # fill, QoS shares, modeled (Table-I) array time/energy, and the
+        # serve.* profiler spans of step().  Scoped per instance so
         # concurrent schedulers never cross-contaminate and
         # telemetry=False is a clean metrics-off baseline (the CI
         # overhead gate).  Process-wide signals (engine dispatch, jit
@@ -864,6 +877,12 @@ class BatchScheduler:
         layers["len"] = layers["len"].at[:, row].set(0)
         lane.cache = dict(lane.cache, layers=layers)
 
+    def _span(self, name: str):
+        """A host span in the profiler's trace (``serve.*``), or nothing
+        with telemetry off.  Outside a profiler capture a span costs
+        about a microsecond."""
+        return TraceAnnotation(name) if self.telemetry else _NO_SPAN
+
     def step(self) -> List[Request]:
         """One window step for every tenant's active slots; returns
         finished requests (across tenants).
@@ -878,98 +897,153 @@ class BatchScheduler:
         prefill; emits its first token when the prompt drains) or its
         last generated token (decode, ``m = 1``).  Empty rows ride along
         at ``m = 0``.  One fixed-shape call serves them all.
+
+        With telemetry on, the call is the host span ``serve.step``, and
+        each lane's phases nest in it as ``serve.admit``,
+        ``serve.window``, ``serve.dispatch``, ``serve.sync`` and
+        ``serve.emit`` (``serve.swap`` first while a swap is in flight);
+        see ``docs/OBSERVABILITY.md``.
         """
-        self._advance_swap()
-        finished: List[Request] = []
-        decoded = False
-        leak = self._leak_now()
-        ex = self.model.executor
+        with self._span("serve.step"):
+            if self._swap is not None:
+                with self._span("serve.swap"):
+                    self._advance_swap()
+            finished: List[Request] = []
+            decoded = False
+            leak = self._leak_now()
+            ex = self.model.executor
+            for t in self._lane_order():
+                lane = self._lanes[t]
+                if lane.paused:
+                    continue
+                with self._span("serve.admit"):
+                    self._admit(lane)
+                if all(s is None for s in lane.slots):
+                    continue
+                with self._span("serve.window"):
+                    toks, m, emit, reg_rows = self._window_inputs(lane)
+                    # the tenant's read-active planes ride as an argument
+                    tiles = ex.resident_tiles(t) if ex is not None else {}
+                    toks_dev, m_dev = jnp.asarray(toks), jnp.asarray(m)
+                with self._span("serve.dispatch"):
+                    tok, lane.cache = lane.decode(lane.params, tiles,
+                                                  toks_dev, lane.cache,
+                                                  m_dev, leak)
+                decoded = True
+                with self._span("serve.sync"):
+                    tok_host = np.asarray(tok)
+                with self._span("serve.emit"):
+                    finished += self._emit(lane, emit, reg_rows, tok_host,
+                                           int(m.sum()))
+            if decoded and self._swap is not None:
+                self._swap.note_decode_step()
+            return finished
+
+    def _window_inputs(self, lane: _Lane):
+        """The lane's (width, chunk) token window and per-row counts
+        ``m``, advancing each row's prefill cursor; also which rows emit
+        (``"admission"``/``"decode"``) and which finish their prefill."""
         c = self.chunk
-        for t in self._lane_order():
-            lane = self._lanes[t]
-            if lane.paused:
+        toks = np.zeros((lane.width, c), np.int32)
+        m = np.zeros((lane.width,), np.int32)
+        emit: List[Optional[str]] = [None] * lane.width
+        reg_rows: List[int] = []
+        for i, req in enumerate(lane.slots):
+            if req is None:
                 continue
-            self._admit(lane)
-            if all(s is None for s in lane.slots):
+            feed = req.feed
+            flen = int(feed.shape[0])
+            if req.fed < flen:
+                piece = feed[req.fed:req.fed + c]
+                toks[i, :piece.shape[0]] = piece
+                m[i] = piece.shape[0]
+                req.fed += int(piece.shape[0])
+                if req.fed >= flen:
+                    # final chunk: the argmax is the request's first
+                    # token — or, on a post-preemption re-admission
+                    # (out non-empty), the continuation of a stream
+                    # that already started
+                    emit[i] = "admission" if not req.out else "decode"
+                    reg_rows.append(i)
+            else:
+                toks[i, 0] = req.out[-1]
+                m[i] = 1
+                emit[i] = "decode"
+        return toks, m, emit, reg_rows
+
+    def _emit(self, lane: _Lane, emit: List[Optional[str]],
+              reg_rows: List[int], tok_host: np.ndarray,
+              useful: int) -> List[Request]:
+        """Hand the step's tokens to their requests, finish and release
+        completed rows, and count the step; returns the finished
+        requests.  ``useful`` is the window positions that fed a real
+        token (prompt tokens plus decoding rows)."""
+        if self.prefix_share and lane.pool is not None:
+            # prefill just completed for these rows: every page
+            # wholly covered by the feed is final on device now —
+            # index it so later common-head admissions alias it
+            for i in reg_rows:
+                if lane.slots[i] is not None:
+                    lane.pool.register_prefix(
+                        i, lane.slots[i].feed.tolist())
+        finished: List[Request] = []
+        n_admit = n_dec = 0
+        for i, req in enumerate(lane.slots):
+            if req is None or emit[i] is None:
                 continue
-            toks = np.zeros((lane.width, c), np.int32)
-            m = np.zeros((lane.width,), np.int32)
-            emit: List[Optional[str]] = [None] * lane.width
-            reg_rows: List[int] = []
-            for i, req in enumerate(lane.slots):
-                if req is None:
-                    continue
-                feed = req.feed
-                flen = int(feed.shape[0])
-                if req.fed < flen:
-                    piece = feed[req.fed:req.fed + c]
-                    toks[i, :piece.shape[0]] = piece
-                    m[i] = piece.shape[0]
-                    req.fed += int(piece.shape[0])
-                    if req.fed >= flen:
-                        # final chunk: the argmax is the request's first
-                        # token — or, on a post-preemption re-admission
-                        # (out non-empty), the continuation of a stream
-                        # that already started
-                        emit[i] = "admission" if not req.out else "decode"
-                        reg_rows.append(i)
-                else:
-                    toks[i, 0] = req.out[-1]
-                    m[i] = 1
-                    emit[i] = "decode"
-            # the tenant's read-active planes ride as an argument
-            tiles = ex.resident_tiles(t) if ex is not None else {}
-            t0 = self.tracer.now()
-            tok, lane.cache = lane.decode(
-                lane.params, tiles, jnp.asarray(toks), lane.cache,
-                jnp.asarray(m), leak)
-            decoded = True
-            tok_host = np.asarray(tok)
-            if self.prefix_share and lane.pool is not None:
-                # prefill just completed for these rows: every page
-                # wholly covered by the feed is final on device now —
-                # index it so later common-head admissions alias it
-                for i in reg_rows:
-                    if lane.slots[i] is not None:
-                        lane.pool.register_prefix(
-                            i, lane.slots[i].feed.tolist())
-            n_admit = n_dec = 0
-            for i, req in enumerate(lane.slots):
-                if req is None or emit[i] is None:
-                    continue
-                req.out.append(int(tok_host[i]))
-                if emit[i] == "admission":
-                    req.t_first = self.tracer.now()
-                    n_admit += 1
-                    if self.metrics.enabled and req.t_submit is not None:
-                        self.metrics.histogram(
-                            "serve_ttft_seconds",
-                            help="submit to first emitted token").observe(
-                            req.t_first - req.t_submit, tenant=lane.tenant)
-                else:
-                    n_dec += 1
-                if len(req.out) >= req.max_new:
-                    req.t_done = self.tracer.now()
-                    self._finish_request(lane, req)
-                    finished.append(req)
-                    self._release_slot(lane, i)
-            self._account_tokens(lane, n_admit, "admission")
-            self._account_tokens(lane, n_dec, "decode")
-            if self.metrics.enabled and (n_admit + n_dec):
-                # every emitted token materialized in this one batched
-                # step, so the per-token latency IS the step wall time —
-                # observed once per emitted token so histogram mass
-                # weights by tokens, not steps
-                dt = self.tracer.now() - t0
-                h = self.metrics.histogram(
-                    "serve_token_latency_seconds",
-                    help="wall time of the decode step that produced "
-                         "each token")
-                for _ in range(n_admit + n_dec):
-                    h.observe(dt, tenant=lane.tenant)
-        if decoded and self._swap is not None:
-            self._swap.note_decode_step()
+            req.out.append(int(tok_host[i]))
+            if emit[i] == "admission":
+                req.t_first = self.tracer.now()
+                n_admit += 1
+                if self.metrics.enabled and req.t_submit is not None:
+                    self.metrics.histogram(
+                        "serve_ttft_seconds",
+                        help="submit to first emitted token").observe(
+                        req.t_first - req.t_submit, tenant=lane.tenant)
+            else:
+                n_dec += 1
+            if len(req.out) >= req.max_new:
+                req.t_done = self.tracer.now()
+                self._finish_request(lane, req)
+                finished.append(req)
+                self._release_slot(lane, i)
+        self._account_tokens(lane, n_admit, "admission")
+        self._account_tokens(lane, n_dec, "decode")
+        if self.metrics.enabled:
+            # the window always computes width x chunk positions, and
+            # the head runs on every one of them
+            computed = lane.width * self.chunk
+            pos = self.metrics.counter(
+                "serve_window_positions_total",
+                help="window positions per step: useful (prompt tokens "
+                     "fed plus decoding rows) and computed (width x "
+                     "chunk)")
+            pos.inc(useful, tenant=lane.tenant, kind="useful")
+            pos.inc(computed, tenant=lane.tenant, kind="computed")
+            rows = self.metrics.counter(
+                "serve_head_rows_total",
+                help="head rows per step: emitted (tokens handed out) "
+                     "and computed (width x chunk)")
+            rows.inc(n_admit + n_dec, tenant=lane.tenant, kind="emitted")
+            rows.inc(computed, tenant=lane.tenant, kind="computed")
         return finished
+
+    def window_hlo(self, tenant: str = "A") -> str:
+        """The compiled HLO text of ``tenant``'s window step, as the
+        device runs it.  Its instruction names are the op names a
+        profiler trace prints, and each instruction's ``op_name``
+        metadata carries the named scopes (``window``, ``attention``,
+        ``mlp``, ``head``, ``sample``), so the two together charge
+        device time to scopes.  Lowers with the lane's own arguments:
+        once the lane has stepped, tracing and compiling both hit JAX's
+        caches (no re-trace counted)."""
+        lane = self._lanes[tenant]
+        ex = self.model.executor
+        tiles = ex.resident_tiles(tenant) if ex is not None else {}
+        toks = jnp.zeros((lane.width, self.chunk), jnp.int32)
+        m = jnp.zeros((lane.width,), jnp.int32)
+        return lane.decode.lower(lane.params, tiles, toks, lane.cache, m,
+                                 self._leak_now()).compile().as_text()
 
     def kv_report(self) -> Dict[str, Dict[str, Any]]:
         """Per-tenant page-pool accounting (paged lanes only): sizes,

@@ -309,3 +309,98 @@ def test_path_calls_registry_view_is_dict_compatible():
     # per-geometry labels ride the registry sample
     assert obs.registry().get("crossstack_dispatch_total",
                               path="reference", geometry="40x24") >= 1
+
+
+# -- serve.* host spans and the window counters ------------------------------
+
+BENCH_SPANS = {"sched.step", "arrivals", "submit", "client_bookkeeping",
+               "bench.traced"}
+
+
+def _traced_step_spans(sched, tmp_path):
+    """``serve.*`` host span names of one ``step()`` under the profiler,
+    in start order, and every host event name seen."""
+    import glob
+
+    from jax.profiler import ProfileData
+    with jax.profiler.trace(str(tmp_path)):
+        sched.step()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    events = [(ev.start_ns, ev.name)
+              for plane in ProfileData.from_file(path).planes
+              if plane.name.startswith("/host:")
+              for line in plane.lines for ev in line.events]
+    names = {n for _, n in events}
+    return [n for _, n in sorted(events) if n.startswith("serve.")], names
+
+
+@pytest.mark.parametrize("swap", [False, True])
+def test_step_emits_serve_spans_in_order(tmp_path, swap):
+    """One step under the profiler: the scheduler's phase spans, in
+    order (``serve.swap`` only while a swap is in flight), and none of
+    the benchmark client's span names."""
+    model = build_model(TINY if swap else DIGITAL)
+    params = model.init(jax.random.PRNGKey(0))
+    sched = BatchScheduler(model, params, n_slots=2, max_len=24)
+    _submit(sched, "A", 2, max_new=8)
+    sched.step()                          # compile outside the trace
+    if swap:
+        sched.begin_hot_swap(finetune_delta(params, scale=0.05, seed=3),
+                             chunks_per_step=1)
+    spans, names = _traced_step_spans(sched, tmp_path)
+    want = ["serve.step", "serve.admit", "serve.window", "serve.dispatch",
+            "serve.sync", "serve.emit"]
+    if swap:
+        want.insert(1, "serve.swap")
+    assert spans == want
+    assert not names & BENCH_SPANS
+
+
+def test_telemetry_off_emits_no_serve_spans(tmp_path):
+    model = build_model(DIGITAL)
+    sched = BatchScheduler(model, model.init(jax.random.PRNGKey(0)),
+                           n_slots=2, max_len=24, telemetry=False)
+    _submit(sched, "A", 2, max_new=8)
+    sched.step()
+    spans, _ = _traced_step_spans(sched, tmp_path)
+    assert spans == []
+
+
+def test_window_counters_match_request_state():
+    """Step by step, the window and head counters move by what the
+    requests' own state says the step did: useful positions = prompt
+    tokens fed + decoding rows, emitted = tokens handed out, computed =
+    width x chunk for both."""
+    model = build_model(DIGITAL)
+    sched = BatchScheduler(model, model.init(jax.random.PRNGKey(0)),
+                           n_slots=3, max_len=32, chunk=4)
+    reqs = [Request(rid=i, max_new=2 + 3 * i,
+                    prompt=jnp.arange(3 + 5 * i, dtype=jnp.int32) % 100)
+            for i in range(4)]
+    for r in reqs:
+        sched.submit(r)
+    reg = sched.metrics
+
+    def counts():
+        return (reg.total("serve_window_positions_total", kind="useful"),
+                reg.total("serve_window_positions_total", kind="computed"),
+                reg.total("serve_head_rows_total", kind="emitted"),
+                reg.total("serve_head_rows_total", kind="computed"))
+
+    steps = 0
+    while not all(r.done for r in reqs):
+        before = [(r.fed, r.feed is not None and r.fed >= len(r.feed),
+                   len(r.out)) for r in reqs]
+        c0 = counts()
+        sched.step()
+        steps += 1
+        useful = tokens = 0
+        for r, (fed0, decoding, nout0) in zip(reqs, before):
+            if r.fed > fed0:
+                useful += r.fed - fed0
+            elif decoding and len(r.out) > nout0:
+                useful += 1
+            tokens += len(r.out) - nout0
+        delta = [b - a for a, b in zip(c0, counts())]
+        assert delta == [useful, 3 * 4, tokens, 3 * 4], steps
+    assert counts()[2] == sum(r.max_new for r in reqs)

@@ -16,6 +16,7 @@ entry written for a described chip cannot be read back without one).
 import dataclasses
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -82,14 +83,25 @@ def test_crossbar_mac_compiles_at_published_widths(
     assert "tpu_custom_call" in lowered.compile().as_text()
 
 
-def _window_args(model, params, tiles, sharding):
-    n_pages = _SLOTS * _MAX_LEN // _PAGE
+def _window_args(model, params, tiles, sharding, slots=_SLOTS):
+    n_pages = slots * _MAX_LEN // _PAGE
     cache = jax.eval_shape(lambda: model.init_paged_cache(
-        _SLOTS, _MAX_LEN, n_pages, _PAGE))
+        slots, _MAX_LEN, n_pages, _PAGE))
     return _on(sharding, (
-        params, tiles, jax.ShapeDtypeStruct((_SLOTS, _CHUNK), jnp.int32),
-        cache, jax.ShapeDtypeStruct((_SLOTS,), jnp.int32),
+        params, tiles, jax.ShapeDtypeStruct((slots, _CHUNK), jnp.int32),
+        cache, jax.ShapeDtypeStruct((slots,), jnp.int32),
         jax.ShapeDtypeStruct((), jnp.float32)))
+
+
+def _op_names(hlo_text):
+    """``op_name`` metadata of every instruction, by instruction."""
+    out = {}
+    for line in hlo_text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?(%\S+) = ", line)
+        op = re.search(r'op_name="([^"]*)"', line)
+        if m and op:
+            out[m.group(1)] = op.group(1)
+    return out
 
 
 def test_digital_window_step_compiles_at_published_widths(one_chip):
@@ -123,3 +135,45 @@ def test_crossbar_window_step_compiles_with_tiles_as_arguments(
     compiled = make_window_step(model).lower(
         *_window_args(model, params, tiles, one_chip)).compile()
     assert compiled.as_text().count("tpu_custom_call") >= len(tiles)
+
+
+def test_digital_window_step_names_its_scopes(one_chip):
+    """The compiled digital window step's ops carry the scopes the
+    profiler reduction charges time to."""
+    model = build_model(dataclasses.replace(get_config("qwen3-4b"),
+                                            n_layers=1))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    text = make_window_step(model).lower(
+        *_window_args(model, params, {}, one_chip)).compile().as_text()
+    ops = _op_names(text).values()
+    for scope in ("window", "attention", "mlp", "head", "sample"):
+        assert any(f"/{scope}/" in op for op in ops), scope
+
+
+def test_crossbar_window_step_names_head_and_kernels(
+        one_chip, compiled_kernels):
+    """A 16-slot x chunk-4 crossbar window step with the published
+    vocabulary: the head's 64 x 152064 ``crossbar_mac`` call sits under
+    the ``head`` scope, an attention op under ``attention``, and every
+    Pallas call keeps the instruction name ``crossbar_mac`` that the
+    trace reduction matches."""
+    cfg = get_config("qwen3-4b", smoke=True)
+    cfg = dataclasses.replace(
+        cfg, vocab=151936, backend="crossbar",
+        xbar=dataclasses.replace(cfg.xbar, use_kernel=True))
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(0))
+    ex = model.executor
+    ex.program_params(params)
+    text = make_window_step(model).lower(*_window_args(
+        model, params, ex.resident_tiles("A"), one_chip,
+        slots=16)).compile().as_text()
+    ops = _op_names(text)
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+             and " = " in ln]
+    assert calls and all(re.match(r"\s*(ROOT )?%crossbar_mac\.\d+ = ", ln)
+                         for ln in calls)
+    head = [re.match(r"\s*(?:ROOT )?(%\S+) = ", ln).group(1)
+            for ln in calls if " = f32[64,152064]" in ln]
+    assert len(head) == 1 and "/head/" in ops[head[0]]
+    assert any("/attention/" in op for op in ops.values())
