@@ -332,7 +332,11 @@ def _build_transformer(cfg: ModelConfig) -> Model:
                                  "v": cross_kv[1].astype(cfg.dtype)}
         return logits, cache
 
-    def decode_step(params, tokens, cache):
+    def decode_step(params, tokens, cache, last=None):
+        """Logits ``(B, S, V)`` for every fed position; with ``last``
+        (``(B,)`` int positions) only row ``b``'s position ``last[b]``
+        reaches the final norm and the head, and the logits are
+        ``(B, 1, V)``."""
         x = T.embed(params["embed"], tokens).astype(cfg.dtype)
         offset = cache["layers"]["len"][0]
         sq = tokens.shape[1]
@@ -346,6 +350,8 @@ def _build_transformer(cfg: ModelConfig) -> Model:
         h, new_layers, _ = _trunk(params, x, pos, caches=cache["layers"],
                                   cross_kv=cross_kv)
         with jax.named_scope("head"):
+            if last is not None:
+                h = jnp.take_along_axis(h, last[:, None, None], axis=1)
             h = L.rmsnorm(h, params["ln_f"])
             logits = _logits(params, h)
         return logits, dict(cache, layers=new_layers)

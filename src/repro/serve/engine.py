@@ -94,7 +94,10 @@ def make_window_step(model: Model, tenant: str = "A") -> Callable:
     ``old_len + m`` — pad positions past a row's count are never
     attendable (causal + length masks hit them with exact -1e30s), so
     the emitted token at row position ``m - 1`` is bit-exact with an
-    unpadded reference.  Because the compiled shape is prompt-length
+    unpadded reference.  Only that position of each row reaches the
+    final norm and the head (``width`` head rows, not
+    ``width x chunk``); an empty slot's row 0 is computed and ignored.
+    Because the compiled shape is prompt-length
     independent, this closure traces exactly once per tenant for ANY
     prompt mix.  The cache (argument 3) is donated.
 
@@ -104,9 +107,9 @@ def make_window_step(model: Model, tenant: str = "A") -> Callable:
     compiled step carries no tile constants: its program text and its
     device footprint do not grow with the bytes programmed.
 
-    The body runs under ``jax.named_scope("window")``, the row select
-    and argmax under ``sample``; the model adds ``attention``, ``mlp``
-    and ``head``.  Scopes name the compiled ops (HLO ``op_name``
+    The body runs under ``jax.named_scope("window")``, the argmax under
+    ``sample``; the model adds ``attention``, ``mlp`` and ``head`` (the
+    row select included).  Scopes name the compiled ops (HLO ``op_name``
     metadata) for the profiler; they change nothing else.
 
     ``leak`` is the write-plane leakage of an in-flight hot-swap as a
@@ -132,14 +135,12 @@ def make_window_step(model: Model, tenant: str = "A") -> Callable:
     def _window(params, tokens, cache, m):
         with jax.named_scope("window"):
             old = cache["layers"]["len"]                 # (L, B)
-            logits, cache = model.decode_step(params, tokens, cache)
+            logits, cache = model.decode_step(params, tokens, cache,
+                                              last=jnp.maximum(m - 1, 0))
             layers = dict(cache["layers"])
             layers["len"] = (old + m[None, :]).astype(old.dtype)
             with jax.named_scope("sample"):
-                sel = jnp.take_along_axis(
-                    logits, jnp.maximum(m - 1, 0)[:, None, None],
-                    axis=1)[:, 0]
-                tok = jnp.argmax(sel.astype(jnp.float32),
+                tok = jnp.argmax(logits[:, 0].astype(jnp.float32),
                                  axis=-1).astype(jnp.int32)
             return tok, dict(cache, layers=layers)
 
@@ -1010,8 +1011,8 @@ class BatchScheduler:
         self._account_tokens(lane, n_admit, "admission")
         self._account_tokens(lane, n_dec, "decode")
         if self.metrics.enabled:
-            # the window always computes width x chunk positions, and
-            # the head runs on every one of them
+            # the window always computes width x chunk positions; the
+            # head reads one of them per slot
             computed = lane.width * self.chunk
             pos = self.metrics.counter(
                 "serve_window_positions_total",
@@ -1022,9 +1023,11 @@ class BatchScheduler:
             pos.inc(computed, tenant=lane.tenant, kind="computed")
             rows = self.metrics.counter(
                 "serve_head_rows_total",
-                help="head rows per step: emitted (tokens handed out) "
-                     "and computed (width x chunk)")
+                help="head rows per step: emitted (tokens handed out), "
+                     "read (one per slot) and computed (the width x "
+                     "chunk window positions offered to the head)")
             rows.inc(n_admit + n_dec, tenant=lane.tenant, kind="emitted")
+            rows.inc(lane.width, tenant=lane.tenant, kind="read")
             rows.inc(computed, tenant=lane.tenant, kind="computed")
         return finished
 

@@ -370,7 +370,7 @@ def test_window_counters_match_request_state():
     """Step by step, the window and head counters move by what the
     requests' own state says the step did: useful positions = prompt
     tokens fed + decoding rows, emitted = tokens handed out, computed =
-    width x chunk for both."""
+    width x chunk for both, and the head reads one row per slot."""
     model = build_model(DIGITAL)
     sched = BatchScheduler(model, model.init(jax.random.PRNGKey(0)),
                            n_slots=3, max_len=32, chunk=4)
@@ -392,6 +392,7 @@ def test_window_counters_match_request_state():
         before = [(r.fed, r.feed is not None and r.fed >= len(r.feed),
                    len(r.out)) for r in reqs]
         c0 = counts()
+        read0 = reg.total("serve_head_rows_total", kind="read")
         sched.step()
         steps += 1
         useful = tokens = 0
@@ -403,4 +404,6 @@ def test_window_counters_match_request_state():
             tokens += len(r.out) - nout0
         delta = [b - a for a, b in zip(c0, counts())]
         assert delta == [useful, 3 * 4, tokens, 3 * 4], steps
+        assert reg.total("serve_head_rows_total",
+                         kind="read") - read0 == 3, steps
     assert counts()[2] == sum(r.max_new for r in reqs)

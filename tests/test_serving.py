@@ -5,9 +5,12 @@ import pytest
 
 from repro import obs
 from repro.configs import get_config
-from repro.models.model import build_model
+from repro.core.engine import EngineConfig
+from repro.core.quant import QuantConfig
+from repro.models.model import ModelConfig, build_model
 from repro.serve.engine import (BatchScheduler, Request, greedy_generate,
-                                make_decode_step, make_prefill_step)
+                                make_decode_step, make_prefill_step,
+                                make_window_step)
 
 
 def _model():
@@ -167,3 +170,69 @@ def test_mixed_prompt_lengths_share_one_closure_bit_exactly():
                      closure="decode", tenant="A") == 1
     assert reg.total("serve_jit_retraces_total") == 0
     assert done[0].out == [int(t) for t in ref]
+
+
+@pytest.mark.parametrize("backend", ["digital", "crossbar"])
+def test_window_head_reads_last_fed_position_only(backend):
+    """The window step runs the head on each slot's last fed position.
+    Against the full-window path (head on every position, then the row
+    at ``m - 1``, then argmax) on a mixed window — a row mid-prompt, a
+    row finishing its prompt, a decoding row, an empty slot — the
+    tokens and the cache are equal, and on the crossbar (compiled
+    kernel, here interpreted) the selected rows' logits are bitwise
+    equal."""
+    cfg = ModelConfig(
+        name="tiny", family="dense", n_layers=1, d_model=32, n_heads=2,
+        n_kv=2, head_dim=16, d_ff=64, vocab=128, backend=backend,
+        dtype=jnp.float32, tie_embeddings=backend == "digital",
+        xbar=EngineConfig(tile_rows=32, tile_cols=32, mode="deepnet",
+                          use_kernel=True,
+                          quant=QuantConfig(w_bits=4, in_bits=6,
+                                            adc_bits=12)))
+    m = build_model(cfg)
+    params = m.init(jax.random.PRNGKey(0))
+    ex = m.executor
+    tiles = {}
+    if ex is not None:
+        ex.program_params(params)
+        tiles = ex.resident_tiles("A")
+    leak = jnp.float32(0.0)
+
+    def scoped(fn):
+        def run(params, tiles, *args):
+            if ex is None:
+                return fn(params, *args)
+            with ex.read_tenant("A"), ex.leak_scope(leak), \
+                    ex.tile_scope(tiles):
+                return fn(params, *args)
+        return jax.jit(run)
+
+    @scoped
+    def heads(params, tokens, cache, m_):
+        idx = jnp.maximum(m_ - 1, 0)
+        full, new = m.decode_step(params, tokens, cache)
+        sel = jnp.take_along_axis(full, idx[:, None, None], axis=1)[:, 0]
+        last, _ = m.decode_step(params, tokens, cache, last=idx)
+        layers = dict(new["layers"], len=cache["layers"]["len"] + m_[None])
+        return sel, last[:, 0], dict(new, layers=layers)
+
+    step = make_window_step(m)
+    toks = jax.random.randint(jax.random.PRNGKey(1), (2, 4, 4), 0,
+                              cfg.vocab - 1).astype(jnp.int32)
+    cache = m.init_cache(4, 16)
+    # the first window leaves rows 0 and 1 mid-prompt and row 2 at the
+    # end of a 3-token prompt; the second mixes every kind of row
+    _, cache = step(params, tiles, toks[0], cache,
+                    jnp.array([4, 4, 3, 0], jnp.int32), leak)
+    m2 = jnp.array([4, 2, 1, 0], jnp.int32)
+    sel, last, want = heads(params, tiles, toks[1], cache, m2)
+    got_tok, got = step(params, tiles, toks[1],
+                        jax.tree.map(jnp.copy, cache), m2, leak)
+    want_tok = jnp.argmax(sel.astype(jnp.float32), axis=-1)
+    assert jnp.array_equal(got_tok, want_tok)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert jnp.array_equal(a, b)
+    if backend == "crossbar":
+        assert jnp.array_equal(last, sel)
+    else:
+        assert jnp.allclose(last, sel, rtol=1e-6, atol=1e-6)

@@ -153,10 +153,10 @@ def test_digital_window_step_names_its_scopes(one_chip):
 def test_crossbar_window_step_names_head_and_kernels(
         one_chip, compiled_kernels):
     """A 16-slot x chunk-4 crossbar window step with the published
-    vocabulary: the head's 64 x 152064 ``crossbar_mac`` call sits under
-    the ``head`` scope, an attention op under ``attention``, and every
-    Pallas call keeps the instruction name ``crossbar_mac`` that the
-    trace reduction matches."""
+    vocabulary: the head reads one row per slot, so its 16 x 152064
+    ``crossbar_mac`` call sits under the ``head`` scope, an attention
+    op under ``attention``, and every Pallas call keeps the instruction
+    name ``crossbar_mac`` that the trace reduction matches."""
     cfg = get_config("qwen3-4b", smoke=True)
     cfg = dataclasses.replace(
         cfg, vocab=151936, backend="crossbar",
@@ -174,6 +174,6 @@ def test_crossbar_window_step_names_head_and_kernels(
     assert calls and all(re.match(r"\s*(ROOT )?%crossbar_mac\.\d+ = ", ln)
                          for ln in calls)
     head = [re.match(r"\s*(?:ROOT )?(%\S+) = ", ln).group(1)
-            for ln in calls if " = f32[64,152064]" in ln]
+            for ln in calls if " = f32[16,152064]" in ln]
     assert len(head) == 1 and "/head/" in ops[head[0]]
     assert any("/attention/" in op for op in ops.values())
