@@ -149,9 +149,7 @@ def run_open(drv: Driver, plans: List[Planned], t_open: float,
         else:
             nxt = t_open + plans[i].due if i < n else t_close
             time.sleep(max(0.0, min(nxt, t_close) - clock()))
-    while (any(not tr.tokens for tr in drv.active)
-           and clock() < t_close + drain_s):
-        drv.step()
+    _drain(drv, t_close, drain_s)
     return t_close
 
 
@@ -170,10 +168,22 @@ def start_closed(drv: Driver, stream: Iterator[Planned],
 
 
 def run_closed(drv: Driver, stream: Iterator[Planned], t_open: float,
-               seconds: float) -> float:
-    """Each client sends its next request as its last one completes."""
+               seconds: float, drain_s: float) -> float:
+    """Each client sends its next request as its last one completes, for
+    ``seconds``; then, with no request sent, step until every request
+    sent has its first token or ``drain_s`` has passed.  Returns the
+    close."""
     t_close = t_open + seconds
     while clock() < t_close:
         for tr in drv.step():
             drv.submit(next(stream), clock(), client=tr.client)
+    _drain(drv, t_close, drain_s)
     return t_close
+
+
+def _drain(drv: Driver, t_close: float, drain_s: float) -> None:
+    """Step until every request due before ``t_close`` has its first
+    token, for at most ``drain_s`` after it."""
+    while (any(not tr.tokens and tr.due < t_close for tr in drv.active)
+           and clock() < t_close + drain_s):
+        drv.step()

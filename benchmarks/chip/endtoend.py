@@ -15,7 +15,8 @@ def p90(values: List[float]) -> Optional[float]:
 
 
 def ttft_p90_ms(run) -> Optional[float]:
-    """p90 over requests due in the window of (first token - due)."""
+    """p90 over requests due in the window of (first token - due); a
+    closed loop's request is due when its client sends it."""
     return p90([(tr.tokens[0] - tr.due) * 1e3 for tr in run.window_tracks
                 if tr.tokens])
 

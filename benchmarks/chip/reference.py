@@ -1,13 +1,13 @@
-"""Plain reference for the qwen3 configurations, and the comparison that
-decides ``correct``.
+"""The comparison that decides ``correct``, and the arithmetic of the
+plain reference's linear layers.
 
-The forward pass is written from the published description of Qwen3
-(RMSNorm, q/k RMSNorm per head, split-half RoPE, grouped-query causal
-attention, SwiGLU MLP, a tied or untied head) in float32 with
-``precision=HIGHEST``, over whole sequences with no cache and no
-batching of rows at different depths.  It imports nothing of the
-program: it reads the benchmark's own weights (``weights.published``)
-and computes its own quantization where the configuration states one.
+The forward pass is the configuration's family's (``spec.family``:
+``logits_at``), written from the model's published description in
+float32 with ``precision=HIGHEST``, over whole sequences with no cache
+and no batching of rows at different depths, with every linear layer
+through ``linear`` here.  It imports nothing of the program: it reads
+the benchmark's own weights (``weights.published``) and computes its own
+quantization where the configuration states one.
 
 A configuration's arithmetic for its linear layers (``arith``):
 
@@ -33,13 +33,14 @@ of the same sequences in place of the served token.
 """
 from __future__ import annotations
 
-import functools
 import math
 from typing import Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+import spec
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -161,101 +162,6 @@ def linear(x, w, arith):
     return y.reshape(*lead, w.shape[1])
 
 
-# -- the model -----------------------------------------------------------------
-
-def rmsnorm(x, g, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * g
-
-
-def rope(x, pos, theta):
-    """Split-half rotary embedding of x (B, S, H, D) at positions (S,)."""
-    d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = pos[:, None].astype(jnp.float32) * inv
-    cos, sin = jnp.cos(ang)[None, :, None], jnp.sin(ang)[None, :, None]
-    x1, x2 = jnp.split(x, 2, axis=-1)
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-@functools.partial(jax.jit, static_argnames=("dims", "arith"))
-def block(x, lp, dims, arith):
-    """One decoder layer over whole sequences x (B, S, d)."""
-    h, kv, hd, eps, theta = dims
-    b, s, d = x.shape
-    pos = jnp.arange(s)
-    a = lp["attn"]
-    y = rmsnorm(x, lp["ln1"], eps)
-    q = linear(y, a["wq"].reshape(d, h * hd), arith).reshape(b, s, h, hd)
-    k = linear(y, a["wk"].reshape(d, kv * hd), arith).reshape(b, s, kv, hd)
-    v = linear(y, a["wv"].reshape(d, kv * hd), arith).reshape(b, s, kv, hd)
-    q = rope(rmsnorm(q, a["q_norm"], eps), pos, theta)
-    k = rope(rmsnorm(k, a["k_norm"], eps), pos, theta)
-    qg = q.reshape(b, s, kv, h // kv, hd)
-    sc = jnp.einsum("bskgh,btkh->bkgst", qg, k,
-                    precision=HIGHEST) * hd ** -0.5
-    causal = pos[:, None] >= pos[None, :]
-    sc = jnp.where(causal, sc, -jnp.inf)
-    p = jax.nn.softmax(sc, axis=-1)
-    o = jnp.einsum("bkgst,btkh->bskgh", p, v, precision=HIGHEST)
-    x = x + linear(o.reshape(b, s, h * hd), a["wo"].reshape(h * hd, d),
-                   arith)
-    m = lp["mlp"]
-    y = rmsnorm(x, lp["ln2"], eps)
-    g = linear(y, m["wg"], arith)
-    u = linear(y, m["wi"], arith)
-    return x + linear(jax.nn.silu(g) * u, m["wo"], arith)
-
-
-@functools.partial(jax.jit, static_argnames=("eps", "arith"))
-def head(x, ln_f, w, eps, arith):
-    return linear(rmsnorm(x, ln_f, eps), w, arith)
-
-
-def logits_at(params, conf, seqs: Sequence[np.ndarray],
-              want: Sequence[np.ndarray], seq_len: int, arith,
-              group: int = 4, pad: int = 128,
-              head_rows: int = 256) -> np.ndarray:
-    """Reference logits (len(concat(want)), vocab) at positions ``want[i]``
-    of sequences ``seqs[i]``.  Sequences go through the layers ``group``
-    at a time, longest first, each group right-padded to its longest
-    rounded up to ``pad`` (at most ``seq_len``); the head reads the
-    wanted rows ``head_rows`` at a time.  So few shapes compile, and
-    padding is never attended to (the mask is causal)."""
-    dims = (conf["num_attention_heads"], conf["num_key_value_heads"],
-            conf["head_dim"], float(conf["rms_norm_eps"]),
-            float(conf["rope_theta"]))
-    vocab = conf["vocab_size"]
-    blocks = params["blocks"]
-    w_head = (params["head"][:, :vocab] if "head" in params
-              else params["embed"]["tok"][:vocab].T)
-    order = sorted(range(len(seqs)), key=lambda i: -len(seqs[i]))
-    per_seq: Dict[int, np.ndarray] = {}
-    with jax.default_matmul_precision("highest"):
-        for g0 in range(0, len(order), group):
-            chunk = order[g0:g0 + group]
-            length = min(seq_len, -(-len(seqs[chunk[0]]) // pad) * pad)
-            toks = np.zeros((group, length), np.int32)
-            for r, i in enumerate(chunk):
-                toks[r, :len(seqs[i])] = seqs[i]
-            x = params["embed"]["tok"][jnp.asarray(toks)]
-            for layer in range(conf["num_hidden_layers"]):
-                lp = jax.tree.map(lambda a, l=layer: a[l], blocks)
-                x = block(x, lp, dims, arith)
-            idx = np.concatenate([r * length + np.asarray(want[i])
-                                  for r, i in enumerate(chunk)])
-            n = len(idx)
-            idx = np.pad(idx, (0, -n % head_rows))
-            rows = x.reshape(group * length, -1)[jnp.asarray(idx)]
-            logits = np.concatenate([
-                np.asarray(head(rows[r0:r0 + head_rows], params["ln_f"],
-                                w_head, dims[3], arith))
-                for r0 in range(0, len(idx), head_rows)])[:n]
-            for i in chunk:
-                per_seq[i], logits = (logits[:len(want[i])],
-                                      logits[len(want[i]):])
-    return np.concatenate([per_seq[i] for i in range(len(seqs))])
-
-
 def gaps(ref: np.ndarray, tokens: np.ndarray) -> np.ndarray:
     """Per position: reference best logit minus the reference logit of
     ``tokens`` (inf for an id outside the vocabulary)."""
@@ -303,6 +209,7 @@ def compare(params, conf, reqs, seq_len: int,
         want.append(np.arange(len(p) - 1, len(p) + len(o) - 1))
         served.append(o)
     served = np.concatenate(served)
+    logits_at = spec.family(conf).logits_at
     ref = logits_at(params, conf, seqs, want, seq_len, arith_for(conf))
     out = {"served": numbers(ref, served)}
     arith = controls_for(conf)

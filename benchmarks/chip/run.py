@@ -9,9 +9,10 @@ builds ``BatchScheduler`` as ``launch/serve.py`` does and warms every
 program the window will run.  The window drives ``submit``/``step``
 with the cell's traffic for ``--seconds`` seconds: an open loop sends
 requests on a Poisson schedule at the cell's fixed rate, a closed loop
-keeps one request in flight per client.  After the window the program's
-state is freed and a plain reference (``reference.py``) checks a seeded
-sample of the served tokens.
+keeps one request in flight per client; then, with nothing more sent,
+it steps on until each request sent has its first token.  After the
+window the program's state is freed and a plain reference
+(``reference.py``) checks a seeded sample of the served tokens.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics,
@@ -60,7 +61,7 @@ import weights  # noqa: E402
 from peaks import peaks_for  # noqa: E402
 from trace_reduce import STRETCH, reduce_xspace  # noqa: E402
 
-DRAIN_S = 120.0          # open loop: stepping after the close, at most
+DRAIN_S = 120.0          # stepping after the close, at most
 TRACE_AT = 0.6           # traced stretch starts this far into the window
 TRACE_S = 4.0            # and lasts about this long (whole steps)
 COMPILE_EVENTS = ("/jax/core/compile/jaxpr_to_mlir_module_duration",
@@ -195,7 +196,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     mark = clock()
     cfg = spec.model_config(conf)
     model = build_model(cfg)
-    params = weights.make_params(cfg, seed)
+    params = weights.make_params(conf, seed)
     jax.block_until_ready(params)
     setup["params_s"] = clock() - mark
 
@@ -230,7 +231,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     if cell.open_loop:
         t_close = drive.run_open(drv, plans, t_open, seconds, DRAIN_S)
     else:
-        t_close = drive.run_closed(drv, stream, t_open, seconds)
+        t_close = drive.run_closed(drv, stream, t_open, seconds, DRAIN_S)
     if tracer is not None:
         tracer.stop()
     drv.on_step = None
@@ -253,12 +254,11 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
 
     if cell.open_loop:
         attempted = len(run.window_tracks)
-        failed = sum(1 for t in run.window_tracks if not t.tokens)
     else:
         attempted = sum(1 for t in drv.tracks
                         if t.submit < t_close and (t.done is None
                                                    or t.done >= t_open))
-        failed = 0
+    failed = sum(1 for t in run.window_tracks if not t.tokens)
     if trace:
         metrics = {}
         for m in cell.per_layer:
@@ -278,7 +278,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool,
     picked = reference.sample(finished, seed)
     t_ref = clock()
     n_cmp = sum(len(r.out) for r in picked)
-    read = (reference.compare(weights.published(params, cfg), conf, picked,
+    read = (reference.compare(weights.published(conf, params), conf, picked,
                               serve["max_len"], controls)
             if picked else {k: {"widest_gap": float("inf"),
                                 "mean_gap": float("inf")}

@@ -4,14 +4,19 @@
 lists the metrics; ``configs/<config>.json`` holds the model as it is
 run, ``mixes/<mix>.json`` the traffic parameters and
 ``cells/<cell>.json`` the serving shape (slots, cache depth, chunk, page
-size and, for an open loop, the fixed rate).  Adding a cell adds files
-and entries; no code here names a cell.
+size and, for an open loop, the fixed rate), and ``families/<name>.py``
+the model family that a configuration's ``reference`` key names.
+Adding a cell, or a model, adds files and entries; no code here names a
+cell or a model.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib
+import importlib.util
 import json
 from pathlib import Path
+from types import ModuleType
 from typing import Any, Dict, List
 
 HERE = Path(__file__).resolve().parent
@@ -65,34 +70,38 @@ def load_cell(name: str, bench_path: Path = ROOT / "BENCHMARK.json",
         chips=int(entry["chips"]))
 
 
+def family(conf: Dict[str, Any]) -> ModuleType:
+    """The model plug-in ``families/<reference>.py`` that the
+    configuration's ``reference`` key names (see ``families``)."""
+    name = conf.get("reference")
+    if name is None:
+        raise ValueError(f"configuration {conf.get('name')!r} has no "
+                         f"'reference' key naming its model plug-in "
+                         f"families/<reference>.py")
+    if not (isinstance(name, str) and name.isidentifier()
+            and importlib.util.find_spec(f"families.{name}")):
+        raise ValueError(f"configuration {conf.get('name')!r}: its reference "
+                         f"{name!r} names no model plug-in: no file "
+                         f"families/{name}.py")
+    return importlib.import_module(f"families.{name}")
+
+
 def model_config(conf: Dict[str, Any]):
     """The program's ``ModelConfig`` for a configuration file."""
+    return family(conf).program_config(conf)
+
+
+def crossbar_engine(conf: Dict[str, Any]):
+    """The program's ``EngineConfig`` for the configuration's
+    ``crossbar`` block (the same keys for every family)."""
     from repro.core.engine import EngineConfig
     from repro.core.quant import QuantConfig
-    from repro.models.model import ModelConfig
 
-    if conf["model_type"] != "qwen3" or conf["hidden_act"] != "silu":
-        raise ValueError(f"{conf['name']}: only qwen3 with silu is wired")
-    if conf["rms_norm_eps"] != 1e-6:
-        raise ValueError("the program's RMSNorm epsilon is fixed at 1e-6")
-    serving = conf["serving"]
-    kw = dict(
-        name=conf["name"], family="dense",
-        n_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
-        n_heads=conf["num_attention_heads"],
-        n_kv=conf["num_key_value_heads"], head_dim=conf["head_dim"],
-        d_ff=conf["intermediate_size"], vocab=conf["vocab_size"],
-        act="swiglu", qk_norm=True, rope_theta=float(conf["rope_theta"]),
-        kv_repeat=serving["kv_repeat"],
-        tie_embeddings=conf["tie_word_embeddings"],
-        backend=serving["backend"])
-    if serving["backend"] == "crossbar":
-        xb = conf["crossbar"]
-        kw["xbar"] = EngineConfig(
-            tile_rows=xb["tile_rows"], tile_cols=xb["tile_cols"],
-            mode=xb["mode"], use_kernel=xb["use_kernel"],
-            quant=QuantConfig(w_bits=xb["w_bits"], in_bits=xb["in_bits"],
-                              adc_bits=xb["adc_bits"],
-                              bits_per_cell=xb["bits_per_cell"],
-                              per_channel=xb["per_channel"]))
-    return ModelConfig(**kw)
+    xb = conf["crossbar"]
+    return EngineConfig(
+        tile_rows=xb["tile_rows"], tile_cols=xb["tile_cols"],
+        mode=xb["mode"], use_kernel=xb["use_kernel"],
+        quant=QuantConfig(w_bits=xb["w_bits"], in_bits=xb["in_bits"],
+                          adc_bits=xb["adc_bits"],
+                          bits_per_cell=xb["bits_per_cell"],
+                          per_channel=xb["per_channel"]))

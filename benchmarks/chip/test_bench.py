@@ -6,6 +6,8 @@ work counts, the trace reduction, and the comparison that decides
 """
 from __future__ import annotations
 
+import dataclasses
+import hashlib
 from pathlib import Path
 
 import jax
@@ -22,13 +24,16 @@ from peaks import peaks_for
 from trace_reduce import union
 
 HERE = Path(__file__).resolve().parent
-TINY = dict(hidden_size=64, intermediate_size=128, num_attention_heads=4,
-            num_key_value_heads=2, head_dim=16, vocab_size=384,
-            num_hidden_layers=2)
+
+
+def tiny_conf(config: str):
+    """The configuration ``config`` cut to its family's test size."""
+    conf = spec.load_json(HERE / "configs" / f"{config}.json")
+    return dict(conf, **spec.family(conf).TINY)
 
 
 def tiny_cell(config: str, mix: str, limits) -> spec.Cell:
-    conf = dict(spec.load_json(HERE / "configs" / f"{config}.json"), **TINY)
+    conf = tiny_conf(config)
     m = spec.load_json(HERE / "mixes" / f"{mix}.json")
     m = dict(m, prompt=dict(m["prompt"], max=min(m["prompt"]["max"], 40)),
              output=dict(m["output"], min=4, max=16))
@@ -64,10 +69,34 @@ def test_open_loop_same_work_every_seed():
 def test_closed_loop_blocks_repeat_lengths():
     mix = spec.load_json(HERE / "mixes" / "decode.json")
     s = traffic.closed_loop(mix, 1000, seed=3)
-    blocks = [[next(s) for _ in range(256)] for _ in range(2)]
+    blocks = [[next(s) for _ in range(mix["clients"])] for _ in range(2)]
     lens = [sorted(p.max_new for p in b) for b in blocks]
     assert lens[0] == lens[1]
-    assert all(64 <= p.max_new <= 512 for p in blocks[0])
+    assert all(16 <= p.max_new <= 512 for p in blocks[0])
+
+
+def test_closed_loop_drains_to_first_token():
+    """After the close a closed loop sends nothing more and steps on
+    until every request sent in the window has its first token, so the
+    wait for the first token is read over all of them."""
+    import drive
+    from repro.models.model import build_model
+    from repro.serve.engine import BatchScheduler
+    cell = tiny_cell("qwen3-4b", "decode", TINY_LIMITS["qwen3-4b"])
+    cfg = spec.model_config(cell.config)
+    model = build_model(cfg)
+    sched = BatchScheduler(model, model.init(jax.random.PRNGKey(0)),
+                           n_slots=4, max_len=64, chunk=4)
+    drv = drive.Driver(sched)
+    stream = traffic.closed_loop(cell.mix, cfg.vocab, seed=4)
+    drive.start_closed(drv, stream, cell.mix["clients"])
+    t_open = drive.clock()
+    t_close = drive.run_closed(drv, stream, t_open, 0.5, drain_s=60.0)
+    sent = [tr for tr in drv.tracks if t_open <= tr.due < t_close]
+    assert sent and all(tr.tokens for tr in sent)
+    # the drain stopped as soon as the last of them had its first token
+    late = [s for s in drv.steps if s.t0 >= t_close]
+    assert not late or max(tr.tokens[0] for tr in sent) == late[-1].t1
 
 
 def test_every_cell_fits_its_cache():
@@ -113,8 +142,9 @@ def test_step_work_hand_counted():
     # per layer: q 2560x4096, k and v 2560x1024, o 4096x2560,
     # gate and up 2560x9728, down 9728x2560
     per_layer = (2560 * 4096 * 2 + 2560 * 1024 * 2 + 2560 * 9728 * 3)
-    assert work.trunk_weights(conf) == 19 * per_layer
-    assert work.head_weights(conf) == 2560 * 151936
+    family = spec.family(conf)
+    assert family.trunk_weights(conf) == 19 * per_layer
+    assert family.head_weights(conf) == 2560 * 151936
     # one decode token at context 100
     flops = work.step_flops(conf, positions=1, context=100, tokens=1)
     assert flops == (2 * 19 * per_layer + 2 * 2560 * 151936
@@ -137,6 +167,111 @@ def test_crossbar_work_hand_counted():
     weights_b = 2 * per_layer + head                 # 8 bits per weight
     io = 3 * (ins + 4 * (cols - 151936)) + 2 * (2560 + 4 * 151936)
     assert nbytes == weights_b + 4 * cols + io
+
+
+# -- model families ------------------------------------------------------------
+
+def _sha256(params) -> str:
+    h = hashlib.sha256()
+    for path, leaf in jax.tree_util.tree_flatten_with_path(params)[0]:
+        h.update(jax.tree_util.keystr(path).encode())
+        h.update(np.asarray(leaf).tobytes())
+    return h.hexdigest()
+
+
+# sha256 of each leaf's path and bytes of the test-size parameters at seed
+# 11 (the program's tree, then its published view), as the harness drew
+# them when Qwen3 was written into its generic files: the family plug-in
+# has to give the same weights, bit for bit, so that no reading moves.
+PARAMS_SHA256 = {
+    "qwen3-4b": (
+        "862a2832884111676bf4bbb6ab00f92f0a06915d8eaac3bb9cc08def332c7247",
+        "ee69b9a52f9c5f3fd898c87f5f9ab6c43db8f18b4a174840ce3ada79fddf044e"),
+    "qwen3-4b-xbar": (
+        "a689e79967d7a0c5c6e5279b2553550dc17e9342cc407a1939a69fcbda72d676",
+        "16b0ca9b2f5b882ad1f073c2880b08f7cb08a9ccda71952e07858f1c576c6b9b"),
+}
+
+
+@pytest.mark.parametrize("config", sorted(PARAMS_SHA256))
+def test_family_params_bit_identical(config):
+    import weights
+    conf = tiny_conf(config)
+    params = weights.make_params(conf, 11)
+    assert (_sha256(params), _sha256(weights.published(conf, params))
+            ) == PARAMS_SHA256[config]
+
+
+def test_unknown_family_is_an_error():
+    conf = spec.load_json(HERE / "configs" / "qwen3-4b.json")
+    with pytest.raises(ValueError, match=r"families/no_such_model\.py"):
+        spec.family(dict(conf, reference="no_such_model"))
+    conf.pop("reference")
+    with pytest.raises(ValueError, match=r"families/<reference>\.py"):
+        spec.model_config(conf)
+
+
+# A family that lives outside the benchmark's directory: Qwen3 under
+# another name, recording every call the harness makes into it.
+ELSEWHERE = '''
+import families.qwen3 as base
+
+CALLS = []
+TINY = base.TINY
+
+
+def _recorded(name):
+    def call(*args, **kwargs):
+        CALLS.append(name)
+        return getattr(base, name)(*args, **kwargs)
+    return call
+
+
+program_config, make_params, published, logits_at, linears, step_flops, \\
+    step_bytes = map(_recorded, ("program_config", "make_params",
+                                 "published", "logits_at", "linears",
+                                 "step_flops", "step_bytes"))
+'''
+
+
+def test_configuration_brings_its_own_family(tmp_path, monkeypatch):
+    """A configuration whose ``reference`` names a plug-in found only in
+    another directory runs a whole cell through it, and the work counts
+    the metric readers take come from it too."""
+    import sys
+    import types
+
+    import families
+    import run as R
+    from drive import Step
+    from metrics import step_mfu, step_roofline
+    (tmp_path / "qwen3_elsewhere.py").write_text(ELSEWHERE)
+    monkeypatch.setattr(families, "__path__",
+                        [str(tmp_path), *families.__path__])
+    monkeypatch.delitem(sys.modules, "families.qwen3_elsewhere",
+                        raising=False)
+    cell = tiny_cell("qwen3-4b", "chat_short", TINY_LIMITS["qwen3-4b"])
+    conf = dict(cell.config, reference="qwen3_elsewhere")
+    out = R.run_cell(dataclasses.replace(cell, config=conf), seed=12,
+                     seconds=2.0, trace=False)
+    plugin = sys.modules["families.qwen3_elsewhere"]
+    assert out["correct"] is True, out["checks"]
+    assert {"program_config", "make_params", "published",
+            "logits_at"} <= set(plugin.CALLS)
+
+    steps = [Step(0.0, 0.05, positions=7, context=90, tokens=4)]
+    xbar = spec.load_json(HERE / "configs" / "qwen3-4b-xbar.json")
+    reads = []
+    for c in (conf, cell.config):
+        run = types.SimpleNamespace(
+            conf=c, window_steps=steps, traced_steps=steps,
+            peaks=peaks_for("TPU v5 lite"),
+            trace=types.SimpleNamespace(step_busy_s=[0.04]))
+        reads.append((step_mfu.read(run), step_roofline.read(run),
+                      crossbar_mac_roofline.step_ops_bytes(
+                          dict(c, crossbar=xbar["crossbar"]), positions=7, tokens=4)))
+    assert reads[0] == reads[1]
+    assert {"linears", "step_flops", "step_bytes"} <= set(plugin.CALLS)
 
 
 # -- trace reduction -----------------------------------------------------------
@@ -246,10 +381,8 @@ def _broken(kind):
             tok, new = real(params, tiles, tokens, cache, m, leak)
             if kind == "token":
                 tok = (tok + 1) % vocab
-            else:   # the KV state comes back unchanged
-                layers = dict(new["layers"], k=cache["layers"]["k"],
-                              v=cache["layers"]["v"])
-                new = dict(new, layers=layers)
+            else:   # the layers' state comes back unchanged
+                new = dict(new, layers=cache["layers"])
             return tok, new
         return step
     return make
@@ -257,7 +390,8 @@ def _broken(kind):
 
 @pytest.mark.parametrize("fault", ["token", "state"])
 @pytest.mark.parametrize("config,mix", [("qwen3-4b", "chat_short"),
-                                        ("qwen3-4b-xbar", "decode")])
+                                        ("qwen3-4b-xbar", "decode"),
+                                        ("qwen3-4b", "decode")])
 def test_planted_fault_reads_incorrect(monkeypatch, fault, config, mix):
     """A whole run, chip check aside, with the timed path broken under
     it: a token altered where the step produces it, or a step that
@@ -272,7 +406,8 @@ def test_planted_fault_reads_incorrect(monkeypatch, fault, config, mix):
 
 
 @pytest.mark.parametrize("config,mix", [("qwen3-4b", "chat_short"),
-                                        ("qwen3-4b-xbar", "decode")])
+                                        ("qwen3-4b-xbar", "decode"),
+                                        ("qwen3-4b", "decode")])
 def test_sound_run_reads_correct(config, mix):
     import run as R
     out = R.run_cell(tiny_cell(config, mix, TINY_LIMITS[config]), seed=12,

@@ -89,8 +89,8 @@ def test_fill_counters_match_client_steps(config):
     (``drive.Step``), and computed = slots x chunk."""
     from repro.models.model import build_model
     from repro.serve.engine import BatchScheduler
-    from test_bench import TINY
-    conf = dict(spec.load_json(HERE / "configs" / f"{config}.json"), **TINY)
+    from test_bench import tiny_conf
+    conf = tiny_conf(config)
     mix = spec.load_json(HERE / "mixes" / "chat_short.json")
     mix = dict(mix, prompt=dict(mix["prompt"], max=24),
                output=dict(mix["output"], min=2, max=6))

@@ -57,10 +57,12 @@ def open_loop(mix, rate: float, seconds: float, vocab: int,
 
 def closed_loop(mix, vocab: int, seed: int) -> Iterator[Planned]:
     """An endless stream of requests for the clients to take in turn:
-    each block of 16 per client repeats the same multiset of lengths in a
-    fresh seeded order."""
+    each block of one request per client repeats the same multiset of
+    lengths in a fresh seeded order.  A window takes a few such blocks
+    whole, so every seed gives it nearly the same prompt and answer
+    tokens."""
     rng = np.random.default_rng([seed, 2])
-    block = 16 * int(mix["clients"])
+    block = int(mix["clients"])
     idx = 0
     while True:
         plen, nout = _lengths(mix, block, rng)
